@@ -6,7 +6,7 @@ from repro.train.phases import bert_phases
 
 
 def main():
-    phases = bert_phases(total_steps=1000)
+    phases = bert_phases(900, 100)
     for ph in phases:
         csv(f"table6/{ph.name}", 0.0,
             f"seq={ph.seq_len} predictions={ph.n_predictions} "

@@ -74,7 +74,7 @@ def worker(args) -> None:
     from repro.configs.base import InputShape, TrainConfig
     from repro.core.amp import make_policy
     from repro.core.collectives import exchange_bytes_per_step
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import api
     from repro.train.train_step import init_train_state, make_train_step_dp
     from repro.utils import tree_count
@@ -169,6 +169,9 @@ def worker(args) -> None:
 
 def run_worker(n: int, args) -> dict:
     env = dict(os.environ)
+    # the worker measures the CPU harness on forced host devices; pinning
+    # its platform keeps it off an accelerator the parent process holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
     env["PYTHONPATH"] = str(REPO / "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")

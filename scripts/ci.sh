@@ -198,7 +198,7 @@ import numpy as np
 from repro.configs import get_config, smoke_variant
 from repro.configs.base import InputShape, TrainConfig
 from repro.core.amp import make_policy
-from repro.core.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import api
 from repro.train.train_step import init_train_state, make_train_step_dp
 
@@ -254,7 +254,7 @@ import numpy as np
 from repro.configs import get_config, smoke_variant
 from repro.configs.base import InputShape, TrainConfig
 from repro.core.amp import make_policy
-from repro.core.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import api
 from repro.train.train_step import init_train_state, make_train_step_dp
 

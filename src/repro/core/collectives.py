@@ -74,7 +74,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import axis_size
 from repro.utils import all_finite
 
 
@@ -88,7 +87,7 @@ def ring_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
     Must be called inside shard_map/pmap with ``axis_name`` bound.
     The array's leading dim is chunked N ways (padded if needed).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis_name)
@@ -149,7 +148,7 @@ def hierarchical_psum(x: jax.Array, fast_axis, slow_axis) -> jax.Array:
     fast = (fast_axis,) if isinstance(fast_axis, str) else tuple(fast_axis)
     nf = 1
     for a in fast:
-        nf *= axis_size(a)
+        nf *= jax.lax.axis_size(a)
     flat = x.reshape(-1)
     if flat.size % nf != 0:
         # single fused psum, not psum(psum(fast), slow): the nested form
@@ -311,7 +310,7 @@ def dequantize_int8(q: jax.Array, scale: jax.Array) -> jax.Array:
 def _group_size(axes) -> int:
     n = 1
     for a in axes:
-        n *= axis_size(a)
+        n *= jax.lax.axis_size(a)
     return n
 
 

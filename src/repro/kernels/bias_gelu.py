@@ -3,7 +3,9 @@
 On GPU the win is kernel-launch overhead + locality; on TPU the chain is a
 single VMEM-resident VPU pass: one HBM read of x, one write of y, with the
 bias broadcast from VMEM.  Tiles are (block_rows, d) with d padded to the
-128-lane register width by the caller.
+128-lane register width by the caller; block_rows shrinks as d grows so the
+double-buffered tiles and the fp32 intermediates stay inside the default
+scoped VMEM (a 256-row tile at d = 4096 does not).
 """
 from __future__ import annotations
 
@@ -15,6 +17,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 SQRT_2_OVER_PI = math.sqrt(2.0 / math.pi)
+# fp32 elements per tile: 512 KiB, so in/out double buffers plus a few fp32
+# temporaries of one tile fit the 16 MiB scoped VMEM at any width
+TILE_ELEMS = 128 * 1024
 
 
 def _bias_gelu_kernel(x_ref, b_ref, o_ref):
@@ -25,14 +30,14 @@ def _bias_gelu_kernel(x_ref, b_ref, o_ref):
     o_ref[...] = (0.5 * y * (1.0 + jnp.tanh(inner))).astype(o_ref.dtype)
 
 
-def bias_gelu(x: jax.Array, b: jax.Array, *, block_rows: int = 256,
-              interpret: bool = False) -> jax.Array:
+def bias_gelu(x: jax.Array, b: jax.Array, *, interpret: bool = False
+              ) -> jax.Array:
     """x: (..., d); b: (d,).  Leading dims are flattened into rows."""
     orig_shape = x.shape
     d = x.shape[-1]
     rows = x.size // d
     x2 = x.reshape(rows, d)
-    block_rows = min(block_rows, rows)
+    block_rows = min(max(32, TILE_ELEMS // d // 32 * 32), rows)
     # pad rows to a multiple of the block
     pad = (-rows) % block_rows
     if pad:

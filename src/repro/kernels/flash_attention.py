@@ -5,11 +5,14 @@ Design (DESIGN.md §2): never materialise the (S, S) score matrix in HBM.
 Grid = (B*H, nq, nk) with the kv dimension innermost and *sequential*
 ("arbitrary" semantics): each (bh, i) q tile keeps running online-softmax
 statistics (m, l) and the output accumulator in VMEM scratch across the nk
-steps.  Block shapes are MXU-aligned: (block_q, Dh) x (block_k, Dh) tiles
-with Dh a multiple of 128 (the caller pads).
+steps.  Block shapes are MXU-aligned: (block_q, Dh) x (block_k, Dh) tiles.
 
-The backward pass reuses the pure-jnp FlashAttention-2 VJP in
-models/layers.py (same math; a Pallas bwd kernel would mirror it).
+Per-row statistics (m, l, the logsumexp and the backward's delta) are
+(block_q, 1) columns.  In HBM they are (B*H, S, 1) arrays: a (1, block_q)
+row block of a (B*H, S) array is not tile-aligned on TPU (its sublane dim
+is 1), while a (1, block_q, 1) block spans the full minor dim.
+
+The backward pass is the FlashAttention-2 pair of kernels below.
 """
 from __future__ import annotations
 
@@ -19,15 +22,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    def _scratch(shape, dtype):
-        return pltpu.VMEM(shape, dtype)
-except ImportError:  # pragma: no cover - CPU-only fallback
-    def _scratch(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype)
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -83,24 +78,23 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
             s = jnp.where(_mask(i, j, block_q, block_k, causal, window),
                           s, NEG_INF)
 
-        m_prev = m_ref[...]
+        m_prev = m_ref[...]                    # (bq, 1)
         l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1)
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + pv
+        acc_ref[...] = acc_ref[...] * corr + pv
         m_ref[...] = m_new
         l_ref[...] = l_new
 
     @pl.when(j == n_k - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
         lse_ref[0] = m_ref[...] + jnp.log(l)
 
 
@@ -139,16 +133,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dh), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, i, j: (bh, i)),
+            pl.BlockSpec((1, block_q, 1), lambda bh, i, j: (bh, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, dh), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            _scratch((block_q,), jnp.float32),
-            _scratch((block_q,), jnp.float32),
-            _scratch((block_q, dh), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, dh), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf)
@@ -184,11 +178,11 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         capped = softcap * jnp.tanh(raw / softcap) if softcap else raw
         mask = _mask(i, j, block_q, block_k, causal, window)
         capped = jnp.where(mask, capped, NEG_INF)
-        p = jnp.exp(capped - lse_ref[0][:, None])
+        p = jnp.exp(capped - lse_ref[0])
         p = jnp.where(mask, p, 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None])
+        ds = p * (dp - delta_ref[0])
         if softcap:
             ds = ds * (1.0 - jnp.square(jnp.where(mask, capped / softcap,
                                                   0.0)))
@@ -223,14 +217,14 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         capped = softcap * jnp.tanh(raw / softcap) if softcap else raw
         mask = _mask(i, j, block_q, block_k, causal, window)
         capped = jnp.where(mask, capped, NEG_INF)
-        p = jnp.exp(capped - lse_ref[0][:, None])
+        p = jnp.exp(capped - lse_ref[0])
         p = jnp.where(mask, p, 0.0)
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None])
+        ds = p * (dp - delta_ref[0])
         if softcap:
             ds = ds * (1.0 - jnp.square(jnp.where(mask, capped / softcap,
                                                   0.0)))
@@ -261,13 +255,13 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal=True, window=0,
     kf = k.reshape(b * h, skv, dh)
     vf = v.reshape(b * h, skv, dh)
     dof = dout.reshape(b * h, sq, dh)
-    lsef = lse.reshape(b * h, sq)
+    lsef = lse.reshape(b * h, sq, 1)
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(b * h, sq)
+                    axis=-1).reshape(b * h, sq, 1)
 
     q_spec = pl.BlockSpec((1, block_q, dh), lambda bh, i, j: (bh, i, 0))
     k_spec = pl.BlockSpec((1, block_k, dh), lambda bh, i, j: (bh, j, 0))
-    r_spec = pl.BlockSpec((1, block_q), lambda bh, i, j: (bh, i))
+    r_spec = pl.BlockSpec((1, block_q, 1), lambda bh, i, j: (bh, i, 0))
 
     dq = pl.pallas_call(
         partial(_flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
@@ -277,14 +271,14 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal=True, window=0,
         in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, sq, dh), q.dtype),
-        scratch_shapes=[_scratch((block_q, dh), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, delta)
 
     # second pass: kv blocks outer, q blocks inner
     q_spec2 = pl.BlockSpec((1, block_q, dh), lambda bh, j, i: (bh, i, 0))
     k_spec2 = pl.BlockSpec((1, block_k, dh), lambda bh, j, i: (bh, j, 0))
-    r_spec2 = pl.BlockSpec((1, block_q), lambda bh, j, i: (bh, i))
+    r_spec2 = pl.BlockSpec((1, block_q, 1), lambda bh, j, i: (bh, i, 0))
     dk, dv = pl.pallas_call(
         partial(_flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
                 causal=causal, window=window, softcap=softcap, scale=scale,
@@ -294,8 +288,8 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal=True, window=0,
         out_specs=[k_spec2, k_spec2],
         out_shape=[jax.ShapeDtypeStruct((b * h, skv, dh), k.dtype),
                    jax.ShapeDtypeStruct((b * h, skv, dh), v.dtype)],
-        scratch_shapes=[_scratch((block_k, dh), jnp.float32),
-                        _scratch((block_k, dh), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32),
+                        pltpu.VMEM((block_k, dh), jnp.float32)],
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, delta)
     return (dq.reshape(b, h, sq, dh), dk.reshape(b, h, skv, dh),

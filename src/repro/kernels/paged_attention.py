@@ -13,9 +13,10 @@ across page steps; pages past ``kv_len`` are skipped entirely (``pl.when``),
 and the tail page is masked per token.
 
 int8 pages: per-(page, kv-head) scales are prefetched alongside the pages as
-``(1, 1)`` blocks and the dequantisation (``int8 * scale``) happens on the
-VMEM-resident tile right after the load -- fused into the attention math, so
-HBM only ever carries the 1-byte representation.
+``(1, 1, 1, 1)`` blocks of a ``(KV, P, 1, 1)`` array and the dequantisation
+(``int8 * scale``) happens on the VMEM-resident tile right after the load --
+fused into the attention math, so HBM only ever carries the 1-byte
+representation.
 
 Page-geometry design note (vs MXU/VPU tiling): the KV load tile is
 ``(page_size, Dh)``.  On TPU the minor dim must span a 128 lane tile --
@@ -38,17 +39,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    def _scratch(shape, dtype):
-        return pltpu.VMEM(shape, dtype)
-except ImportError:  # pragma: no cover - CPU-only fallback
-    pltpu = None
-
-    def _scratch(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype)
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -77,7 +68,7 @@ def _paged_kernel(bt_ref, kvl_ref, q_ref, k_ref, v_ref, *rest,
         k = k_ref[0, 0].astype(jnp.float32)             # (page_size, dh)
         v = v_ref[0, 0].astype(jnp.float32)
         if quantized:  # dequant fused into the KV load
-            k = k * ks_ref[0, 0]
+            k = k * ks_ref[0, 0]                         # (1, 1) scale
             v = v * vs_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -108,11 +99,6 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, kv_len, *,
     """q: (B, H, Dh); pages: (P, page_size, KV, Dh); block_table:
     (B, max_pages); kv_len: (B,).  ``k_scale``/``v_scale`` (P, KV) switch on
     the fused int8 dequant.  Returns (B, H, Dh)."""
-    if pltpu is None:  # pragma: no cover
-        from repro.kernels import ref
-        return ref.paged_decode_attention_ref(
-            q, k_pages, v_pages, block_table, kv_len,
-            k_scale=k_scale, v_scale=v_scale, softcap=softcap)
     b, h, dh = q.shape
     p_total, ps, kvh, _ = k_pages.shape
     mp = block_table.shape[1]
@@ -137,11 +123,12 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, kv_len, *,
     ]
     inputs = [qg, kp, vp]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1),
-                                  lambda bi, hi, pi, bt, kvl: (hi, bt[bi, pi]))
-                     ] * 2
-        inputs += [jnp.swapaxes(k_scale, 0, 1).astype(jnp.float32),
-                   jnp.swapaxes(v_scale, 0, 1).astype(jnp.float32)]
+        # (KV, P, 1, 1): a (1, 1) block of a (KV, P) array is not
+        # tile-aligned on TPU; trailing unit dims make it span the array
+        in_specs += [pl.BlockSpec((1, 1, 1, 1), lambda bi, hi, pi, bt, kvl:
+                                  (hi, bt[bi, pi], 0, 0))] * 2
+        inputs += [jnp.swapaxes(sc, 0, 1).astype(jnp.float32)[..., None, None]
+                   for sc in (k_scale, v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -150,9 +137,9 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, kv_len, *,
         out_specs=pl.BlockSpec((1, 1, g, dh),
                                lambda bi, hi, pi, bt, kvl: (bi, hi, 0, 0)),
         scratch_shapes=[
-            _scratch((g,), jnp.float32),
-            _scratch((g,), jnp.float32),
-            _scratch((g, dh), jnp.float32),
+            pltpu.VMEM((g,), jnp.float32),
+            pltpu.VMEM((g,), jnp.float32),
+            pltpu.VMEM((g, dh), jnp.float32),
         ],
     )
     kernel = partial(_paged_kernel, page_size=ps, softcap=softcap,

@@ -76,7 +76,7 @@ def main(argv=None):
     ap.add_argument("--phase", type=int, default=1)
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args(argv)
-    phase = bert_phases(1000)[args.phase - 1]
+    phase = bert_phases(900, 100)[args.phase - 1]
     out = Path(args.out)
     for strategy in ("psum", "bucketed", "ring"):
         run(strategy, phase, multi_pod=False, out_dir=out)

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 
 from repro.configs import ASSIGNED, get_config, get_shape, INPUT_SHAPES
 from repro.configs.base import TrainConfig
-from repro.core.compat import cost_analysis
 from repro.launch.hlo_cost import analyze as hlo_analyze
 from repro.launch.mesh import HW, make_production_mesh
 from repro.models import api
@@ -126,7 +125,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    xla_cost = cost_analysis(compiled)
+    xla_cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     t0 = time.time()
     scopes = ("flash_attention", "wkv6_kernel", "mamba_ssm_kernel") \

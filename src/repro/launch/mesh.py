@@ -9,9 +9,15 @@ device state (the dry-run sets XLA_FLAGS before building the mesh).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from repro.core.compat import make_mesh
+
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: jax's default is Explicit axes,
+    whereas the sharding rules here place arrays through GSPMD.
+    ``devices`` defaults to every device."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

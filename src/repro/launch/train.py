@@ -42,8 +42,9 @@ from repro.data.pipeline import lm_batches
 from repro.launch.mesh import make_host_mesh
 from repro.models import api
 from repro.sharding import make_rules
-from repro.train.train_step import (init_train_state, make_train_step_dp,
-                                    make_train_step_gspmd)
+from repro.train.train_step import (dp_state_shardings, init_train_state,
+                                    make_train_step_dp, make_train_step_gspmd,
+                                    state_shardings)
 from repro.train.trainer import train_loop
 from repro.utils import logger, tree_count
 
@@ -132,10 +133,14 @@ def main(argv=None):
 
     if args.dp:
         step_fn, _ = make_train_step_dp(cfg, tcfg, mesh, shape)
+        placement = dp_state_shardings(state, mesh)
     else:
         shapes, specs_t = api.abstract_params(cfg)
         step_fn, _ = make_train_step_gspmd(cfg, tcfg, mesh, rules, specs_t,
                                            shapes, shape)
+        placement = state_shardings(specs_t, shapes, mesh, rules)
+    # where the step leaves the state: the step then compiles once
+    state = jax.device_put(state, placement)
 
     class BatchStream:
         """Decorates the LMStream with the extra modality fields while

@@ -26,8 +26,9 @@ Struct = jax.ShapeDtypeStruct
 
 
 def mlm_positions_count(seq_len: int) -> int:
-    """Paper Table 6: 20 predictions at S=128, 80 at S=512 (~15%)."""
-    return max(1, int(round(seq_len * 0.15)) + (0 if seq_len % 8 else 0))
+    """Paper Table 6: 20 predictions at S=128, 80 at S=512 -- 5/32 of the
+    sequence, the 15% masking rate rounded up to a whole ratio."""
+    return max(1, seq_len * 5 // 32)
 
 
 # ---------------------------------------------------------------------------

@@ -33,7 +33,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core.amp import Policy
-from repro.core.compat import shard_map
 from repro.sharding import EMBED, EXPERTS, FF, current_mesh, current_rules
 from repro.models.layers import trunc_normal
 from repro.utils import ceil_div
@@ -246,7 +245,7 @@ def _moe_replicated(params, x, cfg, policy, capacity_factor, mesh, data_axes):
             aux = jax.lax.pmean(aux, data_axes)
         return out.reshape(xl.shape), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(batch_spec, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
@@ -300,7 +299,7 @@ def _moe_a2a(params, x, cfg, policy, capacity_factor, mesh, data_axes,
         aux = jax.lax.pmean(aux, data_axes + ("model",))
         return out.reshape(bl, sl, d), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(batch_spec, "model", None), P(None, None),
                   P("model", None, None), P("model", None, None),

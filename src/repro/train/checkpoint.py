@@ -256,7 +256,10 @@ def restore_checkpoint(ckpt_dir: str, like: Any,
                     raise ValueError(
                         f"leaf {i} ({names[i] if names else '?'}): "
                         f"shape {got.shape} != expected {tuple(want.shape)}")
-            restored = [jax.numpy.asarray(g, dtype=w.dtype)
+            # back onto the template's placement, so a step compiled for
+            # it takes the restored state without compiling again
+            restored = [jax.device_put(np.asarray(g, dtype=w.dtype),
+                                       getattr(w, "sharding", None))
                         for g, w in zip(leaves, flat)]
             return jax.tree_util.tree_unflatten(treedef, restored), s
         except (OSError, ValueError, KeyError, EOFError,

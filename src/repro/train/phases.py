@@ -9,6 +9,7 @@ import dataclasses
 from typing import List
 
 from repro.configs.base import InputShape
+from repro.models.api import mlm_positions_count
 
 
 @dataclasses.dataclass(frozen=True)
@@ -26,13 +27,14 @@ class Phase:
                           "train")
 
 
-def bert_phases(total_steps: int, *, global_batch_p1: int = 4096,
-                global_batch_p2: int = 2048, scale_batch: float = 1.0
-                ) -> List[Phase]:
-    b1 = max(8, int(global_batch_p1 * scale_batch))
-    b2 = max(8, int(global_batch_p2 * scale_batch))
-    p1 = int(round(total_steps * 0.9))
+def bert_phases(phase1_steps: int, phase2_steps: int, *,
+                global_batch_p1: int = 4096, global_batch_p2: int = 2048,
+                learning_rate: float = 1e-4) -> List[Phase]:
+    """The two phases, each with its own step count (the paper splits its
+    epochs 36/4, i.e. 90%/10% of the steps)."""
     return [
-        Phase("phase1", 128, 20, b1, p1, 1e-4),
-        Phase("phase2", 512, 80, b2, total_steps - p1, 1e-4),
+        Phase("phase1", 128, mlm_positions_count(128), global_batch_p1,
+              phase1_steps, learning_rate),
+        Phase("phase2", 512, mlm_positions_count(512), global_batch_p2,
+              phase2_steps, learning_rate),
     ]

@@ -30,7 +30,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import InputShape, ModelConfig, TrainConfig
 from repro.core import collectives as C
-from repro.core.compat import shard_map
 from repro.core.amp import (LossScaleState, Policy, make_loss_scale,
                             make_policy)
 from repro.core.grad_accum import accumulate_gradients
@@ -236,7 +235,10 @@ def batch_shardings(cfg: ModelConfig, batch_tree, mesh: Mesh,
 def make_train_step_gspmd(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
                           rules: ShardingRules, param_specs, param_shapes,
                           shape: InputShape):
-    """jit'd (state, batch) -> (state, metrics) with explicit shardings."""
+    """jit'd (state, batch) -> (state, metrics) with explicit shardings.
+
+    Place the initial state with ``state_shardings`` before the first call
+    so the step compiles once."""
     policy = make_policy(tcfg.precision)
     st_shard = state_shardings(param_specs, param_shapes, mesh, rules)
     b_struct = api.train_batch_struct(cfg, shape)
@@ -260,14 +262,14 @@ def make_train_step_gspmd(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
                 lambda g, s: jax.lax.with_sharding_constraint(g, s),
                 grads, st_shard.opt.master)
 
-    def step(state, batch):
+    def train_step(state, batch):
         with use_sharding_ctx(mesh, rules):
             return train_step_fn(state, batch, cfg=cfg, tcfg=tcfg,
                                  policy=policy,
                                  grad_constraint=grad_constraint)
 
     metrics_shard = None  # let XLA pick (replicated scalars)
-    return jax.jit(step,
+    return jax.jit(train_step,
                    in_shardings=(st_shard, b_shard),
                    out_shardings=(st_shard, metrics_shard),
                    donate_argnums=(0,)), b_struct
@@ -277,14 +279,42 @@ def make_train_step_gspmd(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
 # Paper-faithful pure-DP mode (BERT): shard_map + explicit collectives
 # ---------------------------------------------------------------------------
 
+def _dp_axes(mesh: Mesh) -> Tuple[str, ...]:
+    """Mesh axes the DP batch is split over: every one of pod/data/model."""
+    return tuple(a for a in ("pod", "data", "model") if a in mesh.axis_names)
+
+
+def _dp_state_specs(state: TrainState, all_axes) -> TrainState:
+    # everything replicated except the error-feedback residual, whose
+    # leading world dim is sharded so each worker keeps (and the
+    # checkpoint records) its own buffer
+    err_spec = P(all_axes if len(all_axes) > 1 else all_axes[0])
+    return TrainState(
+        opt=jax.tree_util.tree_map(lambda _: P(), state.opt),
+        loss_scale=jax.tree_util.tree_map(lambda _: P(), state.loss_scale),
+        err=jax.tree_util.tree_map(lambda _: err_spec, state.err))
+
+
+def dp_state_shardings(state: TrainState, mesh: Mesh) -> TrainState:
+    """The placement ``make_train_step_dp``'s step gives the state it
+    returns.  Put the initial state there (``jax.device_put``): a state left
+    on the default device compiles the step once for that placement and
+    again at step 2 for this one."""
+    return jax.tree_util.tree_map(
+        lambda spec: NamedSharding(mesh, spec),
+        _dp_state_specs(state, _dp_axes(mesh)),
+        is_leaf=lambda x: isinstance(x, P))
+
+
 def make_train_step_dp(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
                        shape: InputShape):
-    """Pure data parallelism with explicit gradient exchange (paper §4.4)."""
+    """Pure data parallelism with explicit gradient exchange (paper §4.4).
+
+    Place the initial state with ``dp_state_shardings`` before the first
+    call so the step compiles once."""
     policy = make_policy(tcfg.precision)
-    data_axes = tuple(a for a in ("data",) if a in mesh.axis_names)
     pod_axis = "pod" if "pod" in mesh.axis_names else None
-    all_axes = (("pod",) if pod_axis else ()) + data_axes + \
-        (("model",) if "model" in mesh.axis_names else ())
+    all_axes = _dp_axes(mesh)
     # batch is sharded over every mesh axis in DP mode
     world = 1
     for a in all_axes:
@@ -357,28 +387,17 @@ def make_train_step_dp(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
     batch_spec = P(all_axes if len(all_axes) > 1 else all_axes[0])
     batch_specs = jax.tree_util.tree_map(lambda s: batch_spec, b_struct)
 
-    err_spec = P(all_axes if len(all_axes) > 1 else all_axes[0])
-
-    def state_specs(state):
-        # everything replicated except the error-feedback residual, whose
-        # leading world dim is sharded so each worker keeps (and the
-        # checkpoint records) its own buffer
-        return TrainState(
-            opt=jax.tree_util.tree_map(lambda _: P(), state.opt),
-            loss_scale=jax.tree_util.tree_map(lambda _: P(),
-                                              state.loss_scale),
-            err=jax.tree_util.tree_map(lambda _: err_spec, state.err))
-
-    def sm(state, batch):
+    def train_step(state, batch):
         # check_vma=False: the ppermute-ring / psum_scatter+all_gather
         # strategies produce values that are replicated by construction,
         # which the varying-axes type system cannot verify.
-        fn = shard_map(
+        specs = _dp_state_specs(state, all_axes)
+        fn = jax.shard_map(
             step, mesh=mesh,
-            in_specs=(state_specs(state), batch_specs),
-            out_specs=(state_specs(state), P()),
+            in_specs=(specs, batch_specs),
+            out_specs=(specs, P()),
             check_vma=False,
         )
         return fn(state, batch)
 
-    return jax.jit(sm), b_struct
+    return jax.jit(train_step), b_struct

@@ -22,9 +22,11 @@ injects against):
 * **Step watchdog.**  An EMA of step duration flags hangs/stragglers:
   steps slower than ``watchdog_factor`` x the EMA log a warning and count
   into the ``slow_steps`` metric.
-* **Bounded retry.**  Transient step failures (``TransientStepError``,
-  ``RuntimeError``) are retried up to ``max_retries`` times with linear
-  backoff before giving up.
+* **Bounded retry.**  Transient step failures (``TransientStepError``)
+  are retried up to ``max_retries`` times with linear backoff before giving
+  up.  Any other error -- a compile failure, device OOM or lost device --
+  surfaces on its first attempt: retrying it would only recompile and fail
+  again.
 * **Emergency checkpoint.**  Any exception escaping the loop triggers a
   best-effort ``save_checkpoint`` at the last completed step before
   re-raising (hard crashes -- ``os._exit`` -- by design get nothing;
@@ -151,7 +153,7 @@ def train_loop(step_fn: Callable, state, batches: Iterator, *,
                         faults.maybe_fail(step + 1)
                         state, metrics = step_fn(state, batch)
                         break
-                    except (TransientStepError, RuntimeError) as e:
+                    except TransientStepError as e:
                         if attempt >= max_retries:
                             raise
                         retries_used += 1

@@ -137,7 +137,7 @@ def _make_measure(arch: str, d_model: int, seq: int, global_batch: int,
     from repro.configs import get_config, smoke_variant
     from repro.configs.base import InputShape, TrainConfig
     from repro.core.amp import make_policy
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import api
     from repro.train.train_step import init_train_state, make_train_step_dp
 
@@ -243,6 +243,9 @@ def _cli(argv=None) -> int:
         return 0
 
     env = dict(os.environ)
+    # the worker searches on forced host devices; pinning its platform
+    # keeps it off an accelerator the parent process holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = \
         f"--xla_force_host_platform_device_count={args.devices}"
     env["PYTHONPATH"] = str(repo / "src") + (

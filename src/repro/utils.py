@@ -3,6 +3,8 @@ from __future__ import annotations
 
 import logging
 import math
+import os
+from pathlib import Path
 from typing import Any
 
 import jax
@@ -14,6 +16,24 @@ if not logger.handlers:
     _h.setFormatter(logging.Formatter("[%(levelname)s %(name)s] %(message)s"))
     logger.addHandler(_h)
     logger.setLevel(logging.INFO)
+
+
+REPO_ROOT = Path(__file__).resolve().parents[2]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; entry points call this
+    from ``main()``, never at import.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` is used when set (JAX reads it itself);
+    otherwise the fixed ``<repo>/.jax_cache``.  The directory must not move
+    between runs: a cache under a fresh temporary path never hits.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def tree_bytes(tree: Any) -> int:

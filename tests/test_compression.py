@@ -88,7 +88,7 @@ def test_exchange_bytes_hierarchical_volume_and_ratio():
 
 def test_gspmd_mode_rejects_compression():
     from repro.configs import get_config, smoke_variant
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs.base import InputShape
     from repro.models import api
     from repro.sharding import make_rules
@@ -110,7 +110,7 @@ def test_compressed_reduce_matches_psum_and_feeds_back_error():
     out = run_multidevice("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.core.compat import make_mesh, shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core.collectives import (compressed_reduce_gradients,
                                             quantize_int8, dequantize_int8)
         mesh = make_mesh((4,), ("data",))
@@ -124,7 +124,7 @@ def test_compressed_reduce_matches_psum_and_feeds_back_error():
                     tree, err, strategy="psum", mode=mode,
                     data_axes=("data",), bucket_bytes=64)
                 return red["w"], new_err["w"], fin
-            red, new_err, fin = jax.jit(shard_map(
+            red, new_err, fin = jax.jit(jax.shard_map(
                 f, mesh=mesh, in_specs=P("data", None),
                 out_specs=(P("data", None), P("data", None), P()),
                 check_vma=False))(x)
@@ -148,7 +148,7 @@ def test_compressed_reduce_nonfinite_worker_holds_residual():
     out = run_multidevice("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.core.compat import make_mesh, shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core.collectives import compressed_reduce_gradients
         mesh = make_mesh((4,), ("data",))
         x = jnp.ones((4, 16))
@@ -159,7 +159,7 @@ def test_compressed_reduce_nonfinite_worker_holds_residual():
                 {"w": g}, {"w": e}, strategy="psum", mode="int8",
                 data_axes=("data",), bucket_bytes=1 << 16)
             return red["w"], new_err["w"], fin
-        red, new_err, fin = jax.jit(shard_map(
+        red, new_err, fin = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(P("data", None), P("data", None)),
             out_specs=(P("data", None), P("data", None), P()),
             check_vma=False))(x, err0)
@@ -188,7 +188,7 @@ def test_compressed_exact_resume_with_err_buffer():
                                             save_checkpoint)
         from repro.train.train_step import (init_train_state,
                                             make_train_step_dp)
-        from repro.core.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         cfg = smoke_variant(get_config("bert-large"), d_model=64)
         shape = InputShape("t", 32, 8, "train")
         tcfg = TrainConfig(precision="f32", accum_steps=1, total_steps=10,

@@ -110,6 +110,26 @@ def test_prepare_bert_data_end_to_end(tmp_path):
     assert b["tokens"].shape == (4, 64)
 
 
+@pytest.mark.parametrize("phase_idx,seq,preds", [(0, 128, 20), (1, 512, 80)])
+def test_bert_phase_batches_match_step_struct(tmp_path, phase_idx, seq,
+                                              preds):
+    """Paper Table 6: 20 predictions at seq 128, 80 at 512 -- the loader's
+    batches and the train step's batch struct agree on that count."""
+    from repro.configs import get_config
+    from repro.models import api
+    from repro.train.phases import bert_phases
+
+    phase = bert_phases(1, 1, global_batch_p1=4, global_batch_p2=4)[phase_idx]
+    assert (phase.seq_len, phase.n_predictions) == (seq, preds)
+    prepare_bert_data(str(tmp_path), seq_len=seq,
+                      n_predictions=phase.n_predictions, n_docs=30,
+                      vocab_size=1024, n_shards=2)
+    got = next(iter(ShardedLoader(str(tmp_path), 0, 1, batch=4)))
+    want = api.train_batch_struct(get_config("bert-large"), phase.shape)
+    assert {k: v.shape for k, v in got.items() if k in want} == \
+        {k: v.shape for k, v in want.items()}
+
+
 def test_packed_lm_examples(tok):
     from repro.data.pipeline import build_lm_examples
     docs_text = synth_corpus(n_docs=30, seed=3)

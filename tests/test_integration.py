@@ -130,3 +130,47 @@ def test_moe_router_aux_decreases_imbalance(mesh):
     # aux ~1.0 = balanced; must not blow up and should not exceed start
     assert auxes[-1] < auxes[0] * 1.5
     assert all(np.isfinite(a) for a in auxes)
+
+
+@pytest.mark.parametrize("dp", [False, True], ids=["gspmd", "dp"])
+def test_train_phases_compiles_each_phase_once(tmp_path, mesh, dp):
+    """examples/pretrain_bert.py's phase function: both phases train, each
+    phase compiles its train step exactly once (an initial state placed
+    unlike the step's outputs used to compile it again at step 2)."""
+    import importlib.util
+    from collections import Counter
+
+    from jax import monitoring
+
+    from conftest import REPO
+    from repro.train.phases import bert_phases
+
+    spec = importlib.util.spec_from_file_location(
+        "pretrain_bert", REPO / "examples" / "pretrain_bert.py")
+    pretrain = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(pretrain)
+
+    compiles, current = Counter(), {}
+
+    def on_compile(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration" and \
+                kw.get("fun_name") == "jit(train_step)":
+            compiles[current["phase"]] += 1
+
+    def wrap(phase, step):
+        current["phase"] = phase.name
+        return step
+
+    cfg = smoke_variant(get_config("bert-large"), d_model=64)
+    phases = bert_phases(3, 2, global_batch_p1=8, global_batch_p2=8,
+                         learning_rate=2e-3)
+    monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        _, hist = pretrain.train_phases(cfg, phases, mesh,
+                                        workdir=str(tmp_path), dp=dp,
+                                        accum=2, wrap_step=wrap)
+    finally:
+        monitoring.unregister_event_duration_listener(on_compile)
+    assert compiles == {"phase1": 1, "phase2": 1}
+    assert [h[-1]["step"] for h in hist.values()] == [3, 2]
+    assert all(np.isfinite(m["loss"]) for h in hist.values() for m in h)

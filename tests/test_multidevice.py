@@ -10,25 +10,25 @@ def test_ring_hierarchical_bucketed_equal_psum():
     out = run_multidevice("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.core.compat import make_mesh, shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core.collectives import (ring_all_reduce,
                                             hierarchical_psum,
                                             reduce_gradients)
         mesh = make_mesh((8,), ("d",))
         x = jnp.arange(8 * 37, dtype=jnp.float32).reshape(8, 37)
         ref = jnp.tile(x.sum(0)[None], (8, 1))
-        out = jax.jit(shard_map(lambda x: ring_all_reduce(x, "d"),
+        out = jax.jit(jax.shard_map(lambda x: ring_all_reduce(x, "d"),
                                     mesh=mesh, in_specs=P("d", None),
                                     out_specs=P("d", None)))(x)
         np.testing.assert_allclose(out, ref, rtol=1e-6)
         mesh2 = make_mesh((2, 4), ("pod", "d"))
-        out2 = jax.jit(shard_map(
+        out2 = jax.jit(jax.shard_map(
             lambda x: hierarchical_psum(x, "d", "pod"), mesh=mesh2,
             in_specs=P(("pod", "d"), None),
             out_specs=P(("pod", "d"), None)))(x)
         np.testing.assert_allclose(out2, ref, rtol=1e-6)
         tree = {"a": x, "b": 2 * x}
-        out3 = jax.jit(shard_map(
+        out3 = jax.jit(jax.shard_map(
             lambda t: reduce_gradients(t, strategy="bucketed",
                                        data_axes=("d",), pod_axis="pod",
                                        bucket_bytes=64),
@@ -48,7 +48,7 @@ def test_moe_expert_parallel_matches_dense():
         from repro.models import moe as M
         from repro.core.amp import make_policy
         from repro.sharding import use_sharding_ctx, make_rules
-        from repro.core.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         cfg = smoke_variant(get_config("qwen3-moe-30b-a3b"), d_model=64)
         cfg = dataclasses.replace(cfg, n_experts=8, top_k=2, moe_d_ff=32)
         pol = make_policy("f32")
@@ -88,7 +88,7 @@ def test_dp_strategies_agree_on_real_model():
         from repro.models import api
         from repro.train.train_step import (init_train_state,
                                             make_train_step_dp)
-        from repro.core.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         cfg = smoke_variant(get_config("bert-large"), d_model=64)
         shape = InputShape("t", 32, 32, "train")  # 4 per device, accum 2
         batch = api.make_synth_batch(jax.random.PRNGKey(1), cfg, shape)
@@ -132,7 +132,7 @@ def test_small_mesh_dryrun_lowers():
         from repro.train.train_step import (make_train_step_gspmd,
                                             init_train_state)
         from repro.serve.serve_step import make_decode_step
-        from repro.core.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         rules = make_rules()
         for arch in ("qwen3-moe-30b-a3b", "jamba-1.5-large-398b",
@@ -170,7 +170,7 @@ def test_pure_dp_zero1_mode():
         from repro.sharding import make_rules
         from repro.train.train_step import (init_train_state,
                                             make_train_step_gspmd)
-        from repro.core.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         cfg = smoke_variant(get_config("rwkv6-1.6b"), d_model=128)
         mesh = make_mesh((2, 4), ("data", "model"))
         shape = InputShape("t", 32, 8, "train")
@@ -206,7 +206,7 @@ def test_ring_and_hierarchical_edge_paths_vs_psum():
     out = run_multidevice("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.core.compat import make_mesh, shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core.collectives import ring_all_reduce, hierarchical_psum
         mesh = make_mesh((8,), ("d",))
         # sizes: 40 divisible by 8 (no pad), 37 (pad 3), 5 (< ring size:
@@ -214,7 +214,7 @@ def test_ring_and_hierarchical_edge_paths_vs_psum():
         for size in (40, 37, 5, 1):
             x = jnp.arange(8 * size, dtype=jnp.float32).reshape(8, size)
             ref = np.tile(np.asarray(x).sum(0)[None], (8, 1))
-            got = jax.jit(shard_map(lambda v: ring_all_reduce(v, "d"),
+            got = jax.jit(jax.shard_map(lambda v: ring_all_reduce(v, "d"),
                                     mesh=mesh, in_specs=P("d", None),
                                     out_specs=P("d", None)))(x)
             np.testing.assert_allclose(np.asarray(got), ref, rtol=1e-6,
@@ -225,7 +225,7 @@ def test_ring_and_hierarchical_edge_paths_vs_psum():
         for size in (36, 37):
             x = jnp.arange(8 * size, dtype=jnp.float32).reshape(8, size)
             ref = np.tile(np.asarray(x).sum(0)[None], (8, 1))
-            got = jax.jit(shard_map(
+            got = jax.jit(jax.shard_map(
                 lambda v: hierarchical_psum(v, "d", "pod"), mesh=mesh2,
                 in_specs=P(("pod", "d"), None),
                 out_specs=P(("pod", "d"), None), check_vma=False))(x)
@@ -242,12 +242,12 @@ def test_bert_dp_strategies_on_bigger_mesh_ring_multiaxis():
     out = run_multidevice("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.core.compat import make_mesh, shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core.collectives import ring_all_reduce
         mesh = make_mesh((2, 4), ("data", "model"))
         x = jnp.arange(8 * 11, dtype=jnp.float32).reshape(8, 11)
         ref = jnp.tile(x.sum(0)[None], (8, 1))
-        out = jax.jit(shard_map(
+        out = jax.jit(jax.shard_map(
             lambda x: ring_all_reduce(x, ("data", "model")), mesh=mesh,
             in_specs=P(("data", "model"), None),
             out_specs=P(("data", "model"), None), check_vma=False))(x)

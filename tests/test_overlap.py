@@ -134,7 +134,7 @@ def test_overlap_bit_identical_to_serial_psum_across_accum():
         from repro.configs import get_config, smoke_variant
         from repro.configs.base import InputShape, TrainConfig
         from repro.core.amp import make_policy
-        from repro.core.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models import api
         from repro.train.train_step import (init_train_state,
                                             make_train_step_dp)
@@ -178,7 +178,7 @@ def test_overlap_composes_with_int8_error_feedback_and_resume():
         from repro.configs import get_config, smoke_variant
         from repro.configs.base import InputShape, TrainConfig
         from repro.core.amp import make_policy
-        from repro.core.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models import api
         from repro.train.checkpoint import (restore_checkpoint,
                                             save_checkpoint)
@@ -245,7 +245,7 @@ def test_overlapped_reduce_tree_matches_per_leaf_psum():
     out = run_multidevice("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.core.compat import make_mesh, shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core.collectives import overlapped_reduce_tree
         mesh = make_mesh((4,), ("data",))
         k = jax.random.PRNGKey(0)
@@ -259,7 +259,7 @@ def test_overlapped_reduce_tree_matches_per_leaf_psum():
             ref = jax.tree_util.tree_map(
                 lambda g: jax.lax.psum(g * 0.5, ("data",)) / 4, tree)
             return packed, ref
-        packed, ref = jax.jit(shard_map(
+        packed, ref = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=P("data"),
             out_specs=P("data"), check_vma=False))(xs)
         for k2 in xs:
@@ -274,7 +274,7 @@ def test_overlapped_reduce_tree_matches_per_leaf_psum():
 def test_gspmd_mode_rejects_overlap():
     from repro.configs import get_config, smoke_variant
     from repro.configs.base import InputShape, TrainConfig
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import api
     from repro.sharding import make_rules
     from repro.train.train_step import make_train_step_gspmd
