@@ -4,15 +4,16 @@
       --steps 50 --batch 8 --seq 256 [--smoke] [--precision bf16] \
       [--strategy psum|ring|hierarchical|bucketed] [--accum 4] \
       [--dp --grad-compression none|fp16|int8] \
-      [--overlap --bucket-bytes N --timing-breakdown] \
+      [--overlap --bucket-bytes N] \
       [--ckpt-dir DIR --ckpt-every 100 --resume] [--loss-log FILE]
 
 ``--overlap`` switches the gradient exchange to the overlapped drain
 schedule (packed per-bucket collectives inside the last micro-batch's
-backward; bit-identical losses -- see core/grad_accum.py), and
-``--timing-breakdown`` calibrates compute vs exchange time at startup so
-``--log-every`` lines report compute_s / exchange_s / overlap_frac.
-Both are fingerprinted (ov=/bb=) alongside the wire format.
+backward; bit-identical losses -- see core/grad_accum.py).  It and the
+bucket size are fingerprinted (ov=/bb=) alongside the wire format.  To
+see where a step's time goes, run under ``jax.profiler.trace``: the loop
+and the step carry profiler spans and named scopes (README.md,
+"Profiling a training run").
 
 ``--smoke`` swaps in the reduced same-family config so any architecture can
 be exercised on CPU.  On a one-device host the mesh is (1, n_devices);
@@ -75,11 +76,6 @@ def main(argv=None):
     ap.add_argument("--bucket-bytes", type=int, default=None,
                     help="gradient exchange bucket size in bytes "
                     "(default: TrainConfig.bucket_bytes)")
-    ap.add_argument("--timing-breakdown", action="store_true",
-                    help="calibrate compute vs exchange time at startup "
-                    "(times a no-exchange twin + a serial-schedule twin) "
-                    "and report compute_s/exchange_s/overlap_frac in "
-                    "--log-every output (requires --dp)")
     ap.add_argument("--pure-dp", action="store_true",
                     help="ZeRO-1 pure data parallelism (GSPMD mode)")
     ap.add_argument("--moe-impl", default="a2a")
@@ -105,9 +101,6 @@ def main(argv=None):
     if args.overlap and not args.dp:
         raise SystemExit("--overlap requires --dp (the explicit-collective "
                          "shard_map mode owns the exchange schedule)")
-    if args.timing_breakdown and not args.dp:
-        raise SystemExit("--timing-breakdown requires --dp (the twin it "
-                         "times against swaps the explicit collective out)")
     tcfg_kw = {}
     if args.bucket_bytes is not None:
         tcfg_kw["bucket_bytes"] = args.bucket_bytes
@@ -175,45 +168,6 @@ def main(argv=None):
                    f"seed={args.seed}:comp={args.grad_compression}:"
                    f"ov={int(tcfg.overlap_exchange)}:bb={tcfg.bucket_bytes}")
 
-    timing_calib = None
-    if args.timing_breakdown:
-        import dataclasses
-        import time as _time
-
-        def _median_step_s(fn, st, b, iters=3):
-            st2, m = fn(st, b)
-            jax.block_until_ready(m)
-            ts = []
-            for _ in range(iters):
-                t0 = _time.perf_counter()
-                st2, m = fn(st, b)
-                jax.block_until_ready(m)
-                ts.append(_time.perf_counter() - t0)
-            return float(np.median(ts))
-
-        calib_batch = next(BatchStream())
-        # compute twin: identical step with the collective swapped for the
-        # calibration-only "local" no-exchange strategy
-        tcfg_c = dataclasses.replace(tcfg, collective_strategy="local",
-                                     grad_compression="none",
-                                     overlap_exchange=False)
-        fn_c, _ = make_train_step_dp(cfg, tcfg_c, mesh, shape)
-        st_c = init_train_state(state.opt.master, policy, tcfg_c,
-                                world=mesh.devices.size)
-        compute_s = _median_step_s(fn_c, st_c, calib_batch)
-        # serial twin: same wire config with the overlap schedule off
-        if tcfg.overlap_exchange:
-            tcfg_s = dataclasses.replace(tcfg, overlap_exchange=False)
-            fn_s, _ = make_train_step_dp(cfg, tcfg_s, mesh, shape)
-            st_s = init_train_state(state.opt.master, policy, tcfg_s,
-                                    world=mesh.devices.size)
-            serial_s = _median_step_s(fn_s, st_s, calib_batch)
-        else:
-            serial_s = _median_step_s(step_fn, state, calib_batch)
-        timing_calib = {"compute_s": compute_s, "serial_step_s": serial_s}
-        logger.info("timing calibration: compute %.1fms | serial step "
-                    "%.1fms", compute_s * 1e3, serial_s * 1e3)
-
     metrics_hook = None
     if args.loss_log:
         def metrics_hook(m):
@@ -227,8 +181,7 @@ def main(argv=None):
         ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
         resume=args.resume, metrics_hook=metrics_hook,
         config_fingerprint=fingerprint, seed=args.seed,
-        tokens_per_step=args.batch * args.seq,
-        timing_calib=timing_calib)
+        tokens_per_step=args.batch * args.seq)
     if history:
         logger.info("final loss: %.4f", history[-1]["loss"])
     else:

@@ -7,6 +7,11 @@ Faithful to Devlin et al. as reproduced by Lin et al. 2020:
   * MLM head: dense d->d + GELU + LN + tied decoder + output bias
   * NSP head: tanh pooler on [CLS] + binary classifier
 Loss = masked-LM cross-entropy (labels==-100 ignored) + NSP cross-entropy.
+
+Named scopes (op metadata only; the backward pass keeps them as
+``transpose(jvp(<scope>))``): ``bert.embed`` (embeddings and their norm),
+``bert.attention`` and ``bert.ffn`` (each sublayer with its post-LN
+residual norm), ``bert.heads`` (MLM logits, pooler, NSP, both losses).
 """
 from __future__ import annotations
 
@@ -73,27 +78,32 @@ def apply_bert(params, tokens, type_ids, cfg: ModelConfig, policy: Policy,
                remat: bool = False):
     """Returns (sequence_output (B,S,d), pooled (B,d))."""
     b, s = tokens.shape
-    x = L.embed_tokens(params["embed"], tokens, cfg, policy)
-    x = x + jnp.take(params["embed"]["type"], type_ids, axis=0).astype(x.dtype)
-    x = L.apply_norm(params["embed_norm"], x, cfg, policy)
-    x = lshard(x, "batch", None, None)
+    with jax.named_scope("bert.embed"):
+        x = L.embed_tokens(params["embed"], tokens, cfg, policy)
+        x = x + jnp.take(params["embed"]["type"], type_ids,
+                         axis=0).astype(x.dtype)
+        x = L.apply_norm(params["embed_norm"], x, cfg, policy)
+        x = lshard(x, "batch", None, None)
 
     def block(x, p):
         # post-LN: x = LN(x + attn(x)); x = LN(x + mlp(x))
-        y, _ = L.apply_attention(p["attn"], x, cfg, policy,
-                                 mixer_kind="attn_bidir")
-        x = L.apply_norm(p["attn_norm"], x + y, cfg, policy)
-        y = L.apply_mlp(p["mlp"], x, cfg, policy)
-        x = L.apply_norm(p["mlp_norm"], x + y, cfg, policy)
+        with jax.named_scope("bert.attention"):
+            y, _ = L.apply_attention(p["attn"], x, cfg, policy,
+                                     mixer_kind="attn_bidir")
+            x = L.apply_norm(p["attn_norm"], x + y, cfg, policy)
+        with jax.named_scope("bert.ffn"):
+            y = L.apply_mlp(p["mlp"], x, cfg, policy)
+            x = L.apply_norm(p["mlp_norm"], x + y, cfg, policy)
         return x, None
 
     body = jax.checkpoint(block) if remat else block
     x, _ = jax.lax.scan(body, x, params["blocks"])
 
-    pooled = jnp.tanh(
-        x[:, 0].astype(policy.compute_dtype) @
-        params["pooler"]["w"].astype(policy.compute_dtype) +
-        params["pooler"]["b"].astype(policy.compute_dtype))
+    with jax.named_scope("bert.heads"):
+        pooled = jnp.tanh(
+            x[:, 0].astype(policy.compute_dtype) @
+            params["pooler"]["w"].astype(policy.compute_dtype) +
+            params["pooler"]["b"].astype(policy.compute_dtype))
     return x, pooled
 
 
@@ -126,26 +136,27 @@ def bert_pretrain_loss(params, batch, cfg: ModelConfig, policy: Policy,
     """
     seq_out, pooled = apply_bert(params, batch["tokens"], batch["type_ids"],
                                  cfg, policy, remat=remat)
-    mlm_logits = bert_logits(params, seq_out, cfg, policy,
-                             mlm_positions=batch["mlm_positions"])
-    labels = batch["mlm_labels"]
-    valid = (labels >= 0)
-    lab = jnp.where(valid, labels, 0)
-    logp = jax.nn.log_softmax(mlm_logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
-    mlm_loss = jnp.sum(nll * valid) / jnp.maximum(valid.sum(), 1)
+    with jax.named_scope("bert.heads"):
+        mlm_logits = bert_logits(params, seq_out, cfg, policy,
+                                 mlm_positions=batch["mlm_positions"])
+        labels = batch["mlm_labels"]
+        valid = (labels >= 0)
+        lab = jnp.where(valid, labels, 0)
+        logp = jax.nn.log_softmax(mlm_logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
+        mlm_loss = jnp.sum(nll * valid) / jnp.maximum(valid.sum(), 1)
 
-    cd = policy.compute_dtype
-    nsp_logits = pooled @ params["nsp"]["w"].astype(cd) + \
-        params["nsp"]["b"].astype(cd)
-    nsp_logp = jax.nn.log_softmax(nsp_logits.astype(jnp.float32), axis=-1)
-    nsp_loss = -jnp.mean(
-        jnp.take_along_axis(nsp_logp, batch["nsp_labels"][:, None],
-                            axis=-1)[:, 0])
+        cd = policy.compute_dtype
+        nsp_logits = pooled @ params["nsp"]["w"].astype(cd) + \
+            params["nsp"]["b"].astype(cd)
+        nsp_logp = jax.nn.log_softmax(nsp_logits.astype(jnp.float32), axis=-1)
+        nsp_loss = -jnp.mean(
+            jnp.take_along_axis(nsp_logp, batch["nsp_labels"][:, None],
+                                axis=-1)[:, 0])
 
-    loss = mlm_loss + nsp_loss
-    mlm_acc = jnp.sum((mlm_logits.argmax(-1) == lab) * valid) / \
-        jnp.maximum(valid.sum(), 1)
-    metrics = {"mlm_loss": mlm_loss, "nsp_loss": nsp_loss,
-               "mlm_acc": mlm_acc}
+        loss = mlm_loss + nsp_loss
+        mlm_acc = jnp.sum((mlm_logits.argmax(-1) == lab) * valid) / \
+            jnp.maximum(valid.sum(), 1)
+        metrics = {"mlm_loss": mlm_loss, "nsp_loss": nsp_loss,
+                   "mlm_acc": mlm_acc}
     return loss, metrics

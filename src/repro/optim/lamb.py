@@ -50,33 +50,35 @@ def lamb_update(grads, state: LambState, *, lr, b1: float = 0.9,
 
     ``skip_update``: bool scalar -- when False (e.g. non-finite fp16 grads,
     paper §4.2 dynamic loss scaling), state is returned unchanged except
-    the loss-scale bookkeeping handled by the caller.
+    the loss-scale bookkeeping handled by the caller.  Its ops carry the
+    named scope ``lamb``.
     """
-    step = state.step + 1
-    lr = jnp.asarray(lr, jnp.float32)
+    with jax.named_scope("lamb"):
+        step = state.step + 1
+        lr = jnp.asarray(lr, jnp.float32)
 
-    if use_fused_kernel:
-        from repro.kernels import ops as kops
-        leaf_fn = lambda w, g, m, v: kops.lamb_leaf_update(
-            w, g, m, v, lr=lr, b1=b1, b2=b2, eps=eps, wd=wd,
-            step=step)
-    else:
-        leaf_fn = lambda w, g, m, v: _lamb_leaf(
-            w, g, m, v, lr=lr, b1=b1, b2=b2, eps=eps, wd=wd, step=step,
-            fused=False)
+        if use_fused_kernel:
+            from repro.kernels import ops as kops
+            leaf_fn = lambda w, g, m, v: kops.lamb_leaf_update(
+                w, g, m, v, lr=lr, b1=b1, b2=b2, eps=eps, wd=wd,
+                step=step)
+        else:
+            leaf_fn = lambda w, g, m, v: _lamb_leaf(
+                w, g, m, v, lr=lr, b1=b1, b2=b2, eps=eps, wd=wd, step=step,
+                fused=False)
 
-    new = jax.tree_util.tree_map(leaf_fn, state.master, grads, state.m,
-                                 state.v)
-    outer = jax.tree_util.tree_structure(state.master)
-    inner = jax.tree_util.tree_structure((0, 0, 0))
-    new_w, new_m, new_v = jax.tree_util.tree_transpose(outer, inner, new)
+        new = jax.tree_util.tree_map(leaf_fn, state.master, grads, state.m,
+                                     state.v)
+        outer = jax.tree_util.tree_structure(state.master)
+        inner = jax.tree_util.tree_structure((0, 0, 0))
+        new_w, new_m, new_v = jax.tree_util.tree_transpose(outer, inner, new)
 
-    if skip_update is not None:
-        keep = lambda new_t, old_t: jax.tree_util.tree_map(
-            lambda n, o: jnp.where(skip_update, o, n), new_t, old_t)
-        new_w = keep(new_w, state.master)
-        new_m = keep(new_m, state.m)
-        new_v = keep(new_v, state.v)
-        step = jnp.where(skip_update, state.step, step)
+        if skip_update is not None:
+            keep = lambda new_t, old_t: jax.tree_util.tree_map(
+                lambda n, o: jnp.where(skip_update, o, n), new_t, old_t)
+            new_w = keep(new_w, state.master)
+            new_m = keep(new_m, state.m)
+            new_v = keep(new_v, state.v)
+            step = jnp.where(skip_update, state.step, step)
 
-    return LambState(step, new_w, new_m, new_v)
+        return LambState(step, new_w, new_m, new_v)

@@ -324,9 +324,9 @@ def make_train_step_dp(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
 
     def reduce_fn(tree):
         if strategy == "local":
-            # calibration-only: NO gradient collective (workers diverge!).
-            # The timing breakdown (trainer/benchmarks) times this twin to
-            # split a measured step into compute_s vs exchange_s.
+            # no gradient collective (workers diverge!): the step with its
+            # exchange left out, which benchmarks/train_scaling.py times as
+            # its compute baseline and bench/'s tests plant as a fault.
             red = tree
         elif strategy == "hierarchical" and pod_axis:
             fast = tuple(a for a in all_axes if a != pod_axis)

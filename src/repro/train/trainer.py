@@ -31,6 +31,15 @@ injects against):
   best-effort ``save_checkpoint`` at the last completed step before
   re-raising (hard crashes -- ``os._exit`` -- by design get nothing;
   that is what the atomic checkpoint + resume path is for).
+
+Profiler spans.  Under ``jax.profiler.trace`` each loop iteration is a
+``train.step`` (a ``StepTraceAnnotation`` carrying ``step_num``) holding,
+in order, ``train.data`` (``next(batches)``), ``train.dispatch`` (one per
+attempt at the step call, with its ``attempt`` number), ``train.sync``
+(the non-finite supervision's read of the loss and skip flag: the per-step
+wait for the device) and ``train.checkpoint`` (a save).  The spans only
+mark work the loop does anyway; with no profiler running each costs one
+check.
 """
 from __future__ import annotations
 
@@ -38,6 +47,7 @@ import time
 from typing import Callable, Iterator, Optional
 
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core.amp import LossScaleState, loss_scale_summary
 from repro.train.checkpoint import (load_manifest, restore_checkpoint,
@@ -105,159 +115,132 @@ def train_loop(step_fn: Callable, state, batches: Iterator, *,
                watchdog_factor: float = 10.0,
                faults: Optional[FaultInjector] = None,
                config_fingerprint: Optional[str] = None,
-               seed: Optional[int] = None,
-               timing_calib: Optional[dict] = None):
+               seed: Optional[int] = None):
     """Returns (final_state, history list of metric dicts).
 
     ``batches`` may be a plain iterator; if it also implements
     ``state_dict``/``load_state_dict`` its cursor is checkpointed and
     restored for exact resume.  ``faults`` defaults to an injector built
     from the ``REPRO_FAULTS`` env var (no-op when unset).
-
-    ``timing_calib``: optional ``{"compute_s": float, "serial_step_s":
-    float}`` calibration (launch/train.py times a no-exchange twin and a
-    serial-schedule twin once at startup).  When present, every logged
-    window also reports ``compute_s`` / ``exchange_s`` (mean step time
-    split against the compute twin) and ``overlap_frac`` (the fraction of
-    the serial schedule's exchange time this run hides), so overlap wins
-    are observable per-run, not inferred from benchmarks.
     """
     faults = faults if faults is not None else FaultInjector()
     start = 0
     if resume and ckpt_dir:
         state, start = _resume(state, batches, ckpt_dir, config_fingerprint)
 
-    def _extra():
-        return _checkpoint_extra(batches, state,
-                                 fingerprint=config_fingerprint, seed=seed)
+    def _save(done, **extra):
+        extra = dict(_checkpoint_extra(batches, state, seed=seed,
+                                       fingerprint=config_fingerprint),
+                     **extra)
+        with TraceAnnotation("train.checkpoint"):
+            return save_checkpoint(ckpt_dir, done, state, keep=keep,
+                                   extra=extra)
 
     history = []
     consecutive_skips = total_skips = slow_steps = retries_used = 0
     step = start
     ema_dt: Optional[float] = None
     try:
-        t0 = time.time()
-        window_t0, window_steps = t0, 0
-        window_step_s, window_timed = 0.0, 0
+        window_t0, window_steps = time.time(), 0
         for step in range(start, total_steps):
-            batch = next(batches)
-            t_step = time.perf_counter()
-            faults.maybe_slow(step + 1)  # inside the watchdog's timed window
-            if faults.maybe_nan(step + 1):
-                # forged non-finite step: state kept, update skipped --
-                # the runtime-level mirror of the AMP skip path
-                metrics = {"loss": float("nan"), "skipped": True}
-            else:
-                for attempt in range(max_retries + 1):
-                    try:
-                        faults.maybe_fail(step + 1)
-                        state, metrics = step_fn(state, batch)
-                        break
-                    except TransientStepError as e:
-                        if attempt >= max_retries:
-                            raise
-                        retries_used += 1
+            with StepTraceAnnotation("train.step", step_num=step + 1):
+                with TraceAnnotation("train.data"):
+                    batch = next(batches)
+                t_step = time.perf_counter()
+                faults.maybe_slow(step + 1)  # inside the watchdog's window
+                if faults.maybe_nan(step + 1):
+                    # forged non-finite step: state kept, update skipped --
+                    # the runtime-level mirror of the AMP skip path
+                    metrics = {"loss": float("nan"), "skipped": True}
+                else:
+                    for attempt in range(max_retries + 1):
+                        try:
+                            with TraceAnnotation("train.dispatch",
+                                                 attempt=attempt + 1):
+                                faults.maybe_fail(step + 1)
+                                state, metrics = step_fn(state, batch)
+                            break
+                        except TransientStepError as e:
+                            if attempt >= max_retries:
+                                raise
+                            retries_used += 1
+                            logger.warning(
+                                "step %d attempt %d failed (%s): retrying in "
+                                "%.2fs", step + 1, attempt + 1, e,
+                                retry_backoff_s * (attempt + 1))
+                            time.sleep(retry_backoff_s * (attempt + 1))
+                dt = time.perf_counter() - t_step
+                window_steps += 1
+
+                # --- non-finite supervision (observes the AMP skip flag) ---
+                if max_consecutive_skips is not None:
+                    with TraceAnnotation("train.sync"):
+                        loss_val = float(np.asarray(metrics.get("loss", 0.0)))
+                        skipped = bool(np.asarray(
+                            metrics.get("skipped", False)))
+                    if skipped or not np.isfinite(loss_val):
+                        consecutive_skips += 1
+                        total_skips += 1
+                        if consecutive_skips > max_consecutive_skips:
+                            raise NonFiniteBudgetError(
+                                f"{consecutive_skips} consecutive non-finite/"
+                                f"skipped steps at step {step + 1} (budget "
+                                f"{max_consecutive_skips}): aborting")
+                    else:
+                        consecutive_skips = 0
+
+                # --- step-duration watchdog (EMA baseline; the
+                # compile-bearing first step is excluded from it) ---
+                if step - start >= 1:
+                    if ema_dt is not None and dt > watchdog_factor * ema_dt:
+                        slow_steps += 1
                         logger.warning(
-                            "step %d attempt %d failed (%s): retrying in "
-                            "%.2fs", step + 1, attempt + 1, e,
-                            retry_backoff_s * (attempt + 1))
-                        time.sleep(retry_backoff_s * (attempt + 1))
-            dt = time.perf_counter() - t_step
-            window_steps += 1
-            if step - start >= 1:  # exclude the compile-bearing first step
-                window_step_s += dt
-                window_timed += 1
+                            "watchdog: step %d took %.3fs (> %.0fx the %.3fs "
+                            "EMA) -- straggler or hang?", step + 1, dt,
+                            watchdog_factor, ema_dt)
+                    else:
+                        # slow outliers are excluded from the baseline so one
+                        # straggler does not mask the next
+                        ema_dt = dt if ema_dt is None else \
+                            0.9 * ema_dt + 0.1 * dt
 
-            # --- non-finite supervision (observes the AMP skip flag) ---
-            if max_consecutive_skips is not None:
-                loss_val = float(np.asarray(metrics.get("loss", 0.0)))
-                skipped = bool(np.asarray(metrics.get("skipped", False))) \
-                    or not np.isfinite(loss_val)
-                if skipped:
-                    consecutive_skips += 1
-                    total_skips += 1
-                    if consecutive_skips > max_consecutive_skips:
-                        raise NonFiniteBudgetError(
-                            f"{consecutive_skips} consecutive non-finite/"
-                            f"skipped steps at step {step + 1} (budget "
-                            f"{max_consecutive_skips}): aborting")
-                else:
-                    consecutive_skips = 0
-
-            # --- step-duration watchdog (EMA baseline; the compile-bearing
-            # first step is excluded from the baseline) ---
-            if step - start >= 1:
-                if ema_dt is not None and dt > watchdog_factor * ema_dt:
-                    slow_steps += 1
-                    logger.warning(
-                        "watchdog: step %d took %.3fs (> %.0fx the %.3fs "
-                        "EMA) -- straggler or hang?", step + 1, dt,
-                        watchdog_factor, ema_dt)
-                else:
-                    # slow outliers are excluded from the baseline so one
-                    # straggler does not mask the next
-                    ema_dt = dt if ema_dt is None else \
-                        0.9 * ema_dt + 0.1 * dt
-
-            if (step + 1) % log_every == 0 or step + 1 == total_steps:
-                metrics = {k: float(np.asarray(v))
-                           for k, v in metrics.items()}
-                wdt = time.time() - window_t0
-                metrics["steps_per_s"] = window_steps / max(wdt, 1e-9)
-                if tokens_per_step:
-                    metrics["tokens_per_s"] = metrics["steps_per_s"] * \
-                        tokens_per_step
-                metrics["step"] = step + 1
-                metrics["consecutive_skips"] = consecutive_skips
-                metrics["total_skips"] = total_skips
-                metrics["slow_steps"] = slow_steps
-                metrics["retries"] = retries_used
-                timing_str = ""
-                if timing_calib and window_timed:
-                    mean_dt = window_step_s / window_timed
-                    compute_s = float(timing_calib["compute_s"])
-                    exchange_s = max(0.0, mean_dt - compute_s)
-                    metrics["compute_s"] = compute_s
-                    metrics["exchange_s"] = exchange_s
-                    timing_str = (f"cmp {compute_s * 1e3:.1f}ms | "
-                                  f"xch {exchange_s * 1e3:.1f}ms | ")
-                    serial_s = timing_calib.get("serial_step_s")
-                    if serial_s is not None:
-                        serial_xch = max(0.0, float(serial_s) - compute_s)
-                        if serial_xch > 0:
-                            ovl = 1.0 - exchange_s / serial_xch
-                            metrics["overlap_frac"] = max(0.0, min(1.0, ovl))
-                            timing_str += \
-                                f"ovl {metrics['overlap_frac']:.2f} | "
-                history.append(metrics)
-                logger.info(
-                    "step %d | loss %.4f | %s%s%.1f steps/s",
-                    step + 1, metrics.get("loss", float("nan")),
-                    (f"{metrics['tokens_per_s']:.0f} tok/s | "
-                     if "tokens_per_s" in metrics else ""),
-                    timing_str,
-                    metrics["steps_per_s"])
-                if metrics_hook:
-                    metrics_hook(metrics)
-                window_t0, window_steps = time.time(), 0
-                window_step_s, window_timed = 0.0, 0
-            faults.maybe_crash(step + 1)
-            if ckpt_dir and (step + 1) % ckpt_every == 0:
-                path = save_checkpoint(ckpt_dir, step + 1, state, keep=keep,
-                                       extra=_extra())
-                faults.maybe_torn_write(step + 1, path)
+                if (step + 1) % log_every == 0 or step + 1 == total_steps:
+                    metrics = {k: float(np.asarray(v))
+                               for k, v in metrics.items()}
+                    wdt = time.time() - window_t0
+                    metrics["steps_per_s"] = window_steps / max(wdt, 1e-9)
+                    if tokens_per_step:
+                        metrics["tokens_per_s"] = metrics["steps_per_s"] * \
+                            tokens_per_step
+                    metrics["step"] = step + 1
+                    metrics["consecutive_skips"] = consecutive_skips
+                    metrics["total_skips"] = total_skips
+                    metrics["slow_steps"] = slow_steps
+                    metrics["retries"] = retries_used
+                    history.append(metrics)
+                    logger.info(
+                        "step %d | loss %.4f | %s%.1f steps/s",
+                        step + 1, metrics.get("loss", float("nan")),
+                        (f"{metrics['tokens_per_s']:.0f} tok/s | "
+                         if "tokens_per_s" in metrics else ""),
+                        metrics["steps_per_s"])
+                    if metrics_hook:
+                        metrics_hook(metrics)
+                    window_t0, window_steps = time.time(), 0
+                faults.maybe_crash(step + 1)
+                if ckpt_dir and (step + 1) % ckpt_every == 0:
+                    path = _save(step + 1)
+                    faults.maybe_torn_write(step + 1, path)
     except Exception:
         if ckpt_dir:
             done = step if step < total_steps else total_steps
             try:
-                save_checkpoint(ckpt_dir, done, state, keep=keep,
-                                extra=dict(_extra(), emergency=True))
+                _save(done, emergency=True)
                 logger.warning("emergency checkpoint saved at step %d in %s",
                                done, ckpt_dir)
             except Exception as ce:  # noqa: BLE001 -- best effort only
                 logger.warning("emergency checkpoint failed: %s", ce)
         raise
     if ckpt_dir and start < total_steps:
-        save_checkpoint(ckpt_dir, total_steps, state, keep=keep,
-                        extra=_extra())
+        _save(total_steps)
     return state, history
