@@ -18,7 +18,9 @@ injects against):
   ``max_consecutive_skips`` bounds how many may occur back-to-back before
   the run aborts with an emergency checkpoint instead of burning days on
   a diverged model.  Counts surface as ``consecutive_skips``/
-  ``total_skips`` metrics.
+  ``total_skips`` metrics.  The check runs one step late (``train_loop``
+  says when it catches up); a breach still names the step that broke the
+  budget.
 * **Step watchdog.**  An EMA of step duration flags hangs/stragglers:
   steps slower than ``watchdog_factor`` x the EMA log a warning and count
   into the ``slow_steps`` metric.
@@ -28,17 +30,19 @@ injects against):
   surfaces on its first attempt: retrying it would only recompile and fail
   again.
 * **Emergency checkpoint.**  Any exception escaping the loop triggers a
-  best-effort ``save_checkpoint`` at the last completed step before
-  re-raising (hard crashes -- ``os._exit`` -- by design get nothing;
-  that is what the atomic checkpoint + resume path is for).
+  best-effort ``save_checkpoint`` of the state, numbered by the steps
+  applied to it, before re-raising (hard crashes -- ``os._exit`` -- by
+  design get nothing; that is what the atomic checkpoint + resume path is
+  for).
 
 Profiler spans.  Under ``jax.profiler.trace`` each loop iteration is a
 ``train.step`` (a ``StepTraceAnnotation`` carrying ``step_num``) holding,
 in order, ``train.data`` (``next(batches)``), ``train.dispatch`` (one per
 attempt at the step call, with its ``attempt`` number), ``train.sync``
-(the non-finite supervision's read of the loss and skip flag: the per-step
-wait for the device) and ``train.checkpoint`` (a save).  The spans only
-mark work the loop does anyway; with no profiler running each costs one
+(the non-finite supervision's read of one step's loss and skip flag, with
+that step's number as ``of_step``: step k-1's in step k, and step k's own
+too where the loop drains) and ``train.checkpoint`` (a save).  The spans
+only mark work the loop does anyway; with no profiler running each costs one
 check.
 """
 from __future__ import annotations
@@ -46,6 +50,7 @@ from __future__ import annotations
 import time
 from typing import Callable, Iterator, Optional
 
+import jax
 import numpy as np
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
@@ -73,6 +78,13 @@ def _checkpoint_extra(batches, state, *, fingerprint: Optional[str],
     if isinstance(ls, LossScaleState):
         extra["loss_scale"] = loss_scale_summary(ls)
     return extra
+
+
+def _loss_and_flag(metrics) -> tuple:
+    """A step's loss and skip flag, fetched to the host in one transfer."""
+    loss, skipped = jax.device_get((metrics.get("loss", 0.0),
+                                    metrics.get("skipped", False)))
+    return float(loss), bool(skipped)
 
 
 def _resume(state, batches, ckpt_dir: str, fingerprint: Optional[str]):
@@ -122,6 +134,18 @@ def train_loop(step_fn: Callable, state, batches: Iterator, *,
     ``state_dict``/``load_state_dict`` its cursor is checkpointed and
     restored for exact resume.  ``faults`` defaults to an injector built
     from the ``REPRO_FAULTS`` env var (no-op when unset).
+
+    With ``max_consecutive_skips`` set, step k's loss and skip flag are
+    read (one transfer) after step k+1 is dispatched, so the host's work
+    for the next step overlaps the device's work on this one.  The loop
+    drains -- reads every dispatched step, with nothing queued behind --
+    before each log entry and ``metrics_hook`` call, before each
+    checkpoint, at the end of the call and on any exception; ``drains`` in
+    a history entry counts the drains of its window.  A breach found one
+    step late raises naming the step that broke the budget, after the
+    step dispatched behind it has run; the emergency checkpoint holds that
+    step too.  With ``max_consecutive_skips=None`` the loop reads a step's
+    metrics only to log them.
     """
     faults = faults if faults is not None else FaultInjector()
     start = 0
@@ -137,9 +161,35 @@ def train_loop(step_fn: Callable, state, batches: Iterator, *,
                                    extra=extra)
 
     history = []
-    consecutive_skips = total_skips = slow_steps = retries_used = 0
-    step = start
+    consecutive_skips = total_skips = slow_steps = retries_used = drains = 0
+    applied = start     # steps applied to ``state``
+    prev = None         # (step, metrics) dispatched and not yet read
     ema_dt: Optional[float] = None
+
+    def supervise(n, metrics):
+        """Read step ``n``'s loss and skip flag and count them against the
+        budget."""
+        nonlocal consecutive_skips, total_skips
+        with TraceAnnotation("train.sync", of_step=n):
+            loss, skipped = _loss_and_flag(metrics)
+        if skipped or not np.isfinite(loss):
+            consecutive_skips += 1
+            total_skips += 1
+            if consecutive_skips > max_consecutive_skips:
+                raise NonFiniteBudgetError(
+                    f"{consecutive_skips} consecutive non-finite/skipped "
+                    f"steps at step {n} (budget {max_consecutive_skips}): "
+                    "aborting")
+        else:
+            consecutive_skips = 0
+
+    def drain():
+        nonlocal prev, drains
+        if prev is not None:
+            drains += 1
+            last, prev = prev, None
+            supervise(*last)
+
     try:
         window_t0, window_steps = time.time(), 0
         for step in range(start, total_steps):
@@ -169,25 +219,16 @@ def train_loop(step_fn: Callable, state, batches: Iterator, *,
                                 "%.2fs", step + 1, attempt + 1, e,
                                 retry_backoff_s * (attempt + 1))
                             time.sleep(retry_backoff_s * (attempt + 1))
+                applied = step + 1
                 dt = time.perf_counter() - t_step
                 window_steps += 1
 
-                # --- non-finite supervision (observes the AMP skip flag) ---
+                # --- non-finite supervision (observes the AMP skip flag),
+                # one step late: this step is queued behind the read ---
                 if max_consecutive_skips is not None:
-                    with TraceAnnotation("train.sync"):
-                        loss_val = float(np.asarray(metrics.get("loss", 0.0)))
-                        skipped = bool(np.asarray(
-                            metrics.get("skipped", False)))
-                    if skipped or not np.isfinite(loss_val):
-                        consecutive_skips += 1
-                        total_skips += 1
-                        if consecutive_skips > max_consecutive_skips:
-                            raise NonFiniteBudgetError(
-                                f"{consecutive_skips} consecutive non-finite/"
-                                f"skipped steps at step {step + 1} (budget "
-                                f"{max_consecutive_skips}): aborting")
-                    else:
-                        consecutive_skips = 0
+                    last, prev = prev, (step + 1, metrics)
+                    if last is not None:
+                        supervise(*last)
 
                 # --- step-duration watchdog (EMA baseline; the
                 # compile-bearing first step is excluded from it) ---
@@ -204,9 +245,11 @@ def train_loop(step_fn: Callable, state, batches: Iterator, *,
                         ema_dt = dt if ema_dt is None else \
                             0.9 * ema_dt + 0.1 * dt
 
+                # the last step always logs, so every call ends drained
                 if (step + 1) % log_every == 0 or step + 1 == total_steps:
-                    metrics = {k: float(np.asarray(v))
-                               for k, v in metrics.items()}
+                    drain()
+                    metrics = {k: float(v)
+                               for k, v in jax.device_get(metrics).items()}
                     wdt = time.time() - window_t0
                     metrics["steps_per_s"] = window_steps / max(wdt, 1e-9)
                     if tokens_per_step:
@@ -217,6 +260,7 @@ def train_loop(step_fn: Callable, state, batches: Iterator, *,
                     metrics["total_skips"] = total_skips
                     metrics["slow_steps"] = slow_steps
                     metrics["retries"] = retries_used
+                    metrics["drains"] = drains
                     history.append(metrics)
                     logger.info(
                         "step %d | loss %.4f | %s%.1f steps/s",
@@ -226,18 +270,23 @@ def train_loop(step_fn: Callable, state, batches: Iterator, *,
                         metrics["steps_per_s"])
                     if metrics_hook:
                         metrics_hook(metrics)
-                    window_t0, window_steps = time.time(), 0
+                    window_t0, window_steps, drains = time.time(), 0, 0
                 faults.maybe_crash(step + 1)
                 if ckpt_dir and (step + 1) % ckpt_every == 0:
+                    drain()
                     path = _save(step + 1)
                     faults.maybe_torn_write(step + 1, path)
     except Exception:
-        if ckpt_dir:
-            done = step if step < total_steps else total_steps
+        if prev is not None:            # the dispatched step runs out
             try:
-                _save(done, emergency=True)
+                _loss_and_flag(prev[1])
+            except Exception as pe:  # noqa: BLE001 -- the first error wins
+                logger.warning("a dispatched step failed: %s", pe)
+        if ckpt_dir:
+            try:
+                _save(applied, emergency=True)
                 logger.warning("emergency checkpoint saved at step %d in %s",
-                               done, ckpt_dir)
+                               applied, ckpt_dir)
             except Exception as ce:  # noqa: BLE001 -- best effort only
                 logger.warning("emergency checkpoint failed: %s", ce)
         raise

@@ -85,22 +85,37 @@ def test_one_step_span_per_step(runs, run):
     assert all(a[2] <= b[1] for a, b in zip(steps, steps[1:]))
 
 
-PLAIN = ["train.data", "train.dispatch", "train.sync"]
+DATA, DISPATCH = "train.data", "train.dispatch"
 
 
+def _sync(of_step: int) -> str:
+    return f"train.sync of_step={of_step}"
+
+
+def _named(span) -> str:
+    """A child's name, with the step it reads for a ``train.sync``."""
+    return _sync(int(span[3]["of_step"])) if span[0] == "train.sync" \
+        else span[0]
+
+
+# Step k reads step k-1's loss once step k is dispatched; nothing is pending
+# in a call's first step or after a drain.  The last step (it logs) and a
+# checkpointing step drain their own step too.
 @pytest.mark.parametrize("run, step_num, want", [
-    ("plain", 1, PLAIN),
-    ("plain", STEPS, PLAIN),
-    ("retried", 1, PLAIN),
-    ("retried", 2, ["train.data", "train.dispatch", "train.dispatch",
-                    "train.sync"]),
-    ("checkpointed", 1, PLAIN),
-    ("checkpointed", 2, PLAIN + ["train.checkpoint"]),
+    ("plain", 1, [DATA, DISPATCH]),
+    ("plain", STEPS, [DATA, DISPATCH, _sync(STEPS - 1), _sync(STEPS)]),
+    ("retried", 1, [DATA, DISPATCH]),
+    ("retried", 2, [DATA, DISPATCH, DISPATCH, _sync(1)]),
+    ("checkpointed", 1, [DATA, DISPATCH]),
+    ("checkpointed", 2, [DATA, DISPATCH, _sync(1), _sync(2),
+                         "train.checkpoint"]),
+    ("plain", 3, [DATA, DISPATCH, _sync(2)]),
+    ("checkpointed", 3, [DATA, DISPATCH]),
 ])
 def test_children_nest_in_order(runs, run, step_num, want):
     step = _steps(runs[run])[step_num - 1]
     kids = _children(runs[run], step)
-    assert [sp[0] for sp in kids] == want
+    assert [_named(sp) for sp in kids] == want
     assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
 
 
