@@ -1,8 +1,8 @@
-"""A training cell: the program's BERT pre-training path, driven as a job.
+"""A training cell: the program's pre-training path, driven as a job.
 
 The composition is the one ``examples/pretrain_bert.py:train_phases``
 builds, taken apart so that it runs from ``--seed``: examples from the
-benchmark's generator written as the program's shards (``write_shards``)
+model family's generator written as the program's shards (``write_shards``)
 and read back by its ``ShardedLoader``; the pure-DP psum step of
 ``make_train_step_dp`` with bf16 AMP, LAMB and gradient accumulation; the
 state placed by ``dp_state_shardings``; every step driven by
@@ -11,11 +11,12 @@ state placed by ``dp_state_shardings``; every step driven by
 Set-up builds one step and one state and drives them through their first
 steps by ``train_loop`` on the loader's rows; the window then continues
 the same objects.  The first ``reference.STEPS`` steps are the ones the
-plain reference follows to decide ``correct``.
+plain reference follows to decide ``correct``.  What depends on the model
+(its configuration, weights, rows, loss and reference loss) comes from
+the family module (``bench/families/<family>.py``) that the cell is given.
 """
 from __future__ import annotations
 
-import dataclasses
 import shutil
 import time
 from pathlib import Path
@@ -26,19 +27,16 @@ from jax import monitoring
 from jax.profiler import TraceAnnotation
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import get_config
 from repro.configs.base import InputShape, TrainConfig
 from repro.core.amp import make_policy
 from repro.data.pipeline import ShardedLoader, write_shards
 from repro.launch.mesh import make_mesh
-from repro.models import api
 from repro.train.train_step import (dp_state_shardings, init_train_state,
                                     make_train_step_dp)
 from repro.train.trainer import train_loop
 
 from bench import reference
-from bench.traffic import bert_examples, row_keys
-from bench.weights import init_weights, seed_key
+from bench.seeds import seed_key
 
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
@@ -48,7 +46,6 @@ BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 TIMING_STEPS = 5        # warm-up steps after the reference's, timed
 MIN_WINDOW_STEPS = 10
 N_SHARDS = 4            # as train_phases writes them
-LOSSES = ("loss", "mlm_loss", "nsp_loss")   # what the reference compares
 
 
 class CompileClock:
@@ -73,18 +70,18 @@ class CompileClock:
 
 class Probe:
     """The step as ``train_loop`` calls it: a timestamp at each entry, a
-    ``step.call`` span around the call, and each step's losses and skip
-    flag kept on the device."""
+    ``step.call`` span around the call, and each step's losses (the step
+    metrics named in ``losses``) and skip flag kept on the device."""
 
-    def __init__(self, step):
-        self.step = step
+    def __init__(self, step, losses):
+        self.step, self.names = step, losses
         self.entries, self.losses, self.skipped = [], [], []
 
     def __call__(self, state, batch):
         self.entries.append(time.perf_counter())
         with TraceAnnotation("step.call"):
             state, metrics = self.step(state, batch)
-        self.losses.append([metrics[k] for k in LOSSES])
+        self.losses.append([metrics[k] for k in self.names])
         self.skipped.append(metrics["skipped"])
         return state, metrics
 
@@ -110,39 +107,15 @@ class Feed:
         return batch
 
 
-def model_config(cfg: dict):
-    """The program's ModelConfig for a configuration file."""
-    if cfg["type_vocab_size"] != 2:
-        raise ValueError("the program's BERT has two segment types")
-    return dataclasses.replace(
-        get_config(cfg["arch"]), n_layers=cfg["n_layers"],
-        d_model=cfg["d_model"], n_heads=cfg["n_heads"],
-        n_kv_heads=cfg["n_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["d_ff"], vocab_size=cfg["vocab_size"],
-        max_position=cfg["max_position"], norm_eps=cfg["norm_eps"])
-
-
-def check_layout(cfg: dict, mcfg) -> None:
-    """The benchmark's weights must have the program's parameter layout."""
-    mine = jax.eval_shape(lambda k: init_weights(k, cfg), seed_key(0))
-    theirs, _ = api.abstract_params(mcfg)
-    if jax.tree_util.tree_structure(mine) != \
-            jax.tree_util.tree_structure(theirs) or any(
-                a.shape != b.shape for a, b in zip(
-                    jax.tree_util.tree_leaves(mine),
-                    jax.tree_util.tree_leaves(theirs))):
-        raise ValueError("bench/weights.py no longer matches the program's "
-                         "BERT parameter layout")
-
-
 class TrainCell:
-    """The compiled step of one (configuration, traffic mix) pair on its
-    mesh; ``start(seed)`` gives it a state and a loader."""
+    """The compiled step of one (configuration, traffic mix) pair of a model
+    ``family`` on its mesh; ``start(seed)`` gives it a state and a loader."""
 
     state = key = None
 
-    def __init__(self, cfg: dict, mix: dict, devices, workdir: Path):
-        self.cfg, self.mix, self.workdir = cfg, mix, Path(workdir)
+    def __init__(self, family, cfg: dict, mix: dict, devices, workdir: Path):
+        self.family, self.cfg, self.mix = family, cfg, mix
+        self.workdir = Path(workdir)
         dp = mix["data_parallel"]
         if len(devices) < dp:
             raise ValueError(f"{mix['name']} needs {dp} devices")
@@ -159,13 +132,13 @@ class TrainCell:
             learning_rate=mix["learning_rate"],
             warmup_steps=mix["warmup_steps"], total_steps=mix["total_steps"],
             weight_decay=opt["weight_decay"], grad_clip=opt["grad_clip"])
-        self.mcfg = model_config(cfg)
-        check_layout(cfg, self.mcfg)
+        self.mcfg = family.program_config(cfg)
         shape = InputShape(mix["name"], mix["seq_len"], self.global_batch,
                            "train")
         self.step, _ = make_train_step_dp(self.mcfg, self.tcfg, self.mesh,
                                           shape)
         policy = make_policy(cfg["precision"])
+        init_weights = family.init_weights
 
         def make_state(key):
             return init_train_state(init_weights(key, cfg), policy,
@@ -184,8 +157,9 @@ class TrainCell:
             out_shardings=SingleDeviceSharding(self.devices[0]))
 
     def examples(self, seed: int) -> dict:
-        return bert_examples(seed, self.mix, self.cfg["vocab_size"],
-                             self.mix["rows_per_chip"] * len(self.devices))
+        return self.family.examples(
+            seed, self.mix, self.cfg,
+            self.mix["rows_per_chip"] * len(self.devices))
 
     def loader(self, seed: int, rows: dict) -> ShardedLoader:
         """The program's shards of ``rows``, read back by its loader."""
@@ -212,7 +186,7 @@ class TrainCell:
 
     def first_steps(self, probe, feed) -> tuple:
         """Steps 1..STEPS, with what the reference compares: each step's
-        loss with its MLM and NSP parts, the first gradient as LAMB got it
+        loss with its parts, the first gradient as LAMB got it
         (its first moment over 1 - b1, kept on the host) with its per-leaf
         max-norms, and 2-norms of the weights' change after STEPS steps.
         Returns (readings, the steps' batches)."""
@@ -250,6 +224,7 @@ class TrainCell:
         examples; the rows of a batch that are not among them are counted.
         ``row_share`` < 1 keeps only that leading share of each batch (a
         fault put in the program's place)."""
+        row_keys = self.family.row_keys
         index = {k: i for i, k in enumerate(row_keys(rows))}
         picked, missing = [], 0
         for batch in batches:
@@ -261,8 +236,8 @@ class TrainCell:
         n_micro = max(1, int(self.n_micro * row_share))
         workers = len(self.devices) if row_share == 1.0 else 1
         w0 = self._reference_weights(self.key)
-        out = reference.readings(w0, picked, self.cfg, self.mix, n_micro,
-                                 workers=workers, rq=rq,
+        out = reference.readings(w0, picked, self.family.loss, self.cfg,
+                                 self.mix, n_micro, workers=workers, rq=rq,
                                  device=self.devices[0])
         return out, missing
 

@@ -42,7 +42,8 @@ from bench.run import CACHE, judge, resolve, use_compile_cache  # noqa: E402
 
 def readings(cell: TrainCell, seed: int, what: set, limits: dict) -> dict:
     feed, rows, _ = cell.start(seed)
-    prog, batches = cell.first_steps(Probe(cell.step), feed)
+    prog, batches = cell.first_steps(Probe(cell.step, cell.family.LOSSES),
+                                     feed)
     cell.state = None
     ref, missing = cell.reference_readings(rows, batches)
     out = {"seed": seed}
@@ -80,8 +81,8 @@ def main(argv=None) -> int:
         print("control: needs the cell's TPU chips", file=sys.stderr)
         return 2
     use_compile_cache()
-    cell = TrainCell(spec["config"], spec["mix"], devices[:spec["chips"]],
-                     CACHE / "run" / spec["name"])
+    cell = TrainCell(spec["family"], spec["config"], spec["mix"],
+                     devices[:spec["chips"]], CACHE / "run" / spec["name"])
     what = set(args.what.split(","))
     for seed in (int(s) for s in args.seeds.split(",")):
         t = time.perf_counter()

@@ -1,5 +1,6 @@
-"""Model FLOPs per token (bench/flops.py) times tokens/s per chip, over the
-chip's bf16 peak (bench/peaks.json), in percent."""
+"""Model FLOPs per token (the family's ``train_flops_per_token``) times
+tokens/s per chip, over the chip's bf16 peak (bench/peaks.json), in
+percent."""
 
 
 def read(run):

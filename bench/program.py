@@ -204,8 +204,8 @@ def metadata_stats(xspace: bytes) -> dict:
 
 def read_program(trace_dir) -> tuple:
     """(devices, host spans, window) from the newest trace in
-    ``trace_dir``, in ``trace.read_profile``'s form, with the program's
-    ``train.*`` spans among the host spans and each device's
+    ``trace_dir``, in the form ``trace.reduce_timelines`` takes, with the
+    program's ``train.*`` spans among the host spans and each device's
     ``op_names``.  The window is the ``bench.window`` span, else the
     stretch from the first ``train.step`` to the end of the last."""
     from jax.profiler import ProfileData

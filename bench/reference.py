@@ -1,11 +1,9 @@
-"""Plain float32 reference of the BERT pre-training step, and its readings.
+"""Plain float32 reference of a pre-training step, and its readings.
 
-Written from the model's description with ``jax.numpy`` alone; it imports
-nothing of the program.  Every matmul runs at the highest precision.  It
-follows the BERT the program defines (``repro/models/bert.py``), which
-departs from the published model in three ways that PERF.md lists: no
-biases on the attention projections, no padding mask in attention, and
-weight decay on every parameter.
+The loss is the family's (``loss`` in ``bench/families/<family>.py``),
+written from the model's description with ``jax.numpy`` and ``mm`` alone;
+neither it nor this module takes anything from the program.  Every matmul
+runs at the highest precision.
 
 The step it follows: the global batch split into micro-batches of
 consecutive rows (``n_micro`` = data-parallel workers x accumulation), the
@@ -24,7 +22,6 @@ backward pass, on their gradients.
 """
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import jax
@@ -55,77 +52,18 @@ def fp8_round(x):
 fp8_round.defvjp(lambda x: (_e4m3(x), None), lambda _, g: (_e4m3(g),))
 
 
-def _mm(eq, a, b, rq):
+def mm(eq, a, b, rq):
+    """One einsum of a family's reference loss: its operands rounded by
+    ``rq``, run at the highest precision."""
     return jnp.einsum(eq, rq(a), rq(b), precision=HIGHEST)
 
 
-def _layer_norm(x, p, eps):
-    mean = jnp.mean(x, -1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mean), -1, keepdims=True)
-    return (x - mean) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
-
-
-def _gelu(x):
-    return 0.5 * x * (1.0 + jnp.tanh(
-        math.sqrt(2.0 / math.pi) * (x + 0.044715 * x ** 3)))
-
-
-def bert_loss(w, batch, cfg, rq=exact):
-    """MLM + NSP loss of one micro-batch, and its two parts.  ``rq`` rounds
-    every value that
-    the program holds in its compute precision: matmul operands and
-    outputs, the residual stream, LayerNorm and activation outputs."""
-    eps = cfg["norm_eps"]
-    tokens = batch["tokens"]
-    s = tokens.shape[1]
-    x = rq(rq(w["embed"]["tok"][tokens] + w["embed"]["pos"][:s][None])
-           + w["embed"]["type"][batch["type_ids"]])
-    x = rq(_layer_norm(x, w["embed_norm"], eps))
-
-    def layer(x, p):
-        a = p["attn"]
-        q = rq(_mm("bsd,dhk->bshk", x, a["wq"], rq))
-        k = rq(_mm("bsd,dhk->bshk", x, a["wk"], rq))
-        v = rq(_mm("bsd,dhk->bshk", x, a["wv"], rq))
-        scores = _mm("bqhk,bshk->bhqs", q, k, rq) / math.sqrt(q.shape[-1])
-        probs = jax.nn.softmax(scores, axis=-1)
-        o = rq(_mm("bhqs,bshk->bqhk", probs, v, rq))
-        y = rq(_mm("bqhk,hkd->bqd", o, a["wo"], rq))
-        x = rq(_layer_norm(rq(x + y), p["attn_norm"], eps))
-        m = p["mlp"]
-        hid = rq(_gelu(rq(_mm("bsd,df->bsf", x, m["wi"], rq) + m["bi"])))
-        y = rq(_mm("bsf,fd->bsd", hid, m["wo"], rq) + m["bo"])
-        x = rq(_layer_norm(rq(x + y), p["mlp_norm"], eps))
-        return x, None
-
-    x, _ = jax.lax.scan(jax.checkpoint(layer), x, w["blocks"])
-    pooled = rq(jnp.tanh(_mm("bd,de->be", x[:, 0], w["pooler"]["w"], rq)
-                         + w["pooler"]["b"]))
-    h = jnp.take_along_axis(x, batch["mlm_positions"][..., None], axis=1)
-    h = rq(_gelu(rq(_mm("bpd,de->bpe", h, w["mlm_transform"]["w"], rq)
-                    + w["mlm_transform"]["b"])))
-    h = rq(_layer_norm(h, w["mlm_norm"], eps))
-    logits = rq(_mm("bpd,vd->bpv", h, w["embed"]["tok"], rq) + w["mlm_bias"])
-    labels = batch["mlm_labels"]
-    valid = labels >= 0
-    logp = jax.nn.log_softmax(logits, -1)
-    nll = -jnp.take_along_axis(logp, jnp.where(valid, labels, 0)[..., None],
-                               -1)[..., 0]
-    mlm = jnp.sum(nll * valid) / jnp.maximum(valid.sum(), 1)
-    nsp_logits = rq(_mm("bd,dc->bc", pooled, w["nsp"]["w"], rq)
-                    + w["nsp"]["b"])
-    nsp_logp = jax.nn.log_softmax(nsp_logits, -1)
-    nsp = -jnp.mean(jnp.take_along_axis(
-        nsp_logp, batch["nsp_labels"][:, None], -1))
-    return mlm + nsp, jnp.stack([mlm, nsp])
-
-
-@partial(jax.jit, static_argnames=("cfg_items", "n_micro", "rq"))
-def _loss_and_grad(w, batch, *, cfg_items, n_micro, rq):
+@partial(jax.jit, static_argnames=("loss_fn", "cfg_items", "n_micro", "rq"))
+def _loss_and_grad(w, batch, *, loss_fn, cfg_items, n_micro, rq):
     cfg = dict(cfg_items)
     micro = jax.tree_util.tree_map(
         lambda a: a.reshape((n_micro, -1) + a.shape[1:]), batch)
-    grad_fn = jax.value_and_grad(lambda w, mb: bert_loss(w, mb, cfg, rq),
+    grad_fn = jax.value_and_grad(lambda w, mb: loss_fn(w, mb, cfg, rq),
                                  has_aux=True)
 
     def body(carry, mb):
@@ -193,14 +131,14 @@ def leaf_change_norms(new, old):
     return leaf_norms(jax.tree_util.tree_map(jnp.subtract, new, old))
 
 
-def readings(w0, batches, cfg: dict, sched: dict, n_micro: int,
+def readings(w0, batches, loss_fn, cfg: dict, sched: dict, n_micro: int,
              workers: int = 1, rq=exact, device=None) -> dict:
     """From ``w0`` over ``batches`` (host arrays), for each of the first
-    ``STEPS`` steps: the loss and, as the program reports them, its MLM and
-    NSP parts on each worker's last micro-batch, averaged over the
-    ``workers``; the first clipped gradient (``grad``, on the device) and
-    its per-leaf max-norms; per-leaf 2-norms of the weights' change after
-    ``STEPS`` steps."""
+    ``STEPS`` steps: the loss of the family's ``loss_fn`` and, as the
+    program reports them, each of its parts on each worker's last
+    micro-batch, averaged over the ``workers``; the first clipped gradient
+    (``grad``, on the device) and its per-leaf max-norms; per-leaf 2-norms
+    of the weights' change after ``STEPS`` steps."""
     device = device or jax.devices()[0]
     cfg_items = tuple(sorted((k, v) for k, v in cfg.items()
                              if isinstance(v, (int, float, str))))
@@ -211,11 +149,12 @@ def readings(w0, batches, cfg: dict, sched: dict, n_micro: int,
     losses, grad = [], None
     for step in range(1, len(batches) + 1):
         batch = jax.device_put(batches[step - 1], device)
-        loss, g, parts = _loss_and_grad(w, batch, cfg_items=cfg_items,
-                                        n_micro=n_micro, rq=rq)
+        loss, g, parts = _loss_and_grad(w, batch, loss_fn=loss_fn,
+                                        cfg_items=cfg_items, n_micro=n_micro,
+                                        rq=rq)
         per = n_micro // workers
         last = np.asarray(parts)[per - 1::per].mean(axis=0)
-        losses.append([float(loss), float(last[0]), float(last[1])])
+        losses.append([float(loss)] + [float(x) for x in last])
         w, m, v, g = _clip_lamb(w, g, m, v, jnp.float32(step),
                                 jnp.float32(learning_rate(step, sched)),
                                 opt_items=opt_items)
@@ -232,7 +171,7 @@ def compare(prog: dict, ref: dict) -> dict:
     """The numbers that decide ``correct``.
 
     loss_gap: the root mean square, over the steps, of the gaps to the
-    reference of the loss and of its MLM and NSP parts, in nats.
+    reference of the loss and of each of its parts, in nats.
     grad_gap, change_gap: the worst leaf's |norm - reference norm|, over
     the larger of that leaf's reference norm and the median leaf's: the
     max-norm of the first gradient, the 2-norm of the change after the

@@ -6,9 +6,11 @@
 The cell's configuration, traffic mix, per-layer metrics and limits are
 found by name from ``BENCHMARK.json``: ``bench/configs/<config>.json``,
 ``bench/traffic/<traffic>.json``, ``bench/metrics/<metric>.py`` and
-``bench/limits/<workload>.json``.  Set-up builds the step and its state,
-drives the first steps that the reference follows and a few timed ones,
-then one ``train_loop`` call runs for the window.  ``--trace 1`` records
+``bench/limits/<workload>.json``; the configuration's ``family`` names the
+module that knows its model, ``bench/families/<family>.py`` (its names are
+``FAMILY_NAMES``).  Set-up builds the step and its state, drives the first
+steps that the reference follows and a few timed ones, then one
+``train_loop`` call runs for the window.  ``--trace 1`` records
 the window with the profiler and reports the per-layer metrics instead of
 the end-to-end ones.  After the window the plain reference decides
 ``correct``.  The last line of standard output is the result as JSON; the
@@ -25,7 +27,6 @@ import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
-import shutil  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
 
@@ -37,13 +38,16 @@ CACHE = ROOT / "bench" / ".cache"
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
+from jax._src.lib import _profiler  # noqa: E402
 from jax.profiler import TraceAnnotation  # noqa: E402
 
-from bench import flops, reference, trace  # noqa: E402
+from bench import peaks, reference, trace  # noqa: E402
 from bench.cell import (  # noqa: E402
     MIN_WINDOW_STEPS, TIMING_STEPS, CompileClock, Probe, TrainCell, peak_bytes)
 
 CHECKS = ("loss_gap", "grad_gap", "grad_err", "change_gap", "rows_missing")
+FAMILY_NAMES = ("program_config", "init_weights", "examples", "row_keys",
+                "LOSSES", "loss", "train_flops_per_token", "tiny")
 PROFILE_OPTIONS = jax.profiler.ProfileOptions()
 PROFILE_OPTIONS.python_tracer_level = 0   # no event per Python call
 
@@ -62,8 +66,9 @@ def resolve(name: str, root: Path = ROOT) -> dict:
     per_layer = [m for m in bench["per_layer"]
                  if reports(m) and m["moves"] in e2e_names]
     read_json = lambda p: json.loads((root / p).read_text())
-    return {"name": name, "chips": wl["chips"],
-            "config": read_json(conf["file"]),
+    cfg = read_json(conf["file"])
+    return {"name": name, "chips": wl["chips"], "config": cfg,
+            "family": family(cfg["family"], root),
             "mix": read_json(f"bench/traffic/{wl['traffic']}.json"),
             "limits": read_json(f"bench/limits/{name}.json"),
             "end_to_end": e2e, "per_layer": per_layer,
@@ -80,37 +85,57 @@ def judge(found: dict, limits: dict, failed: int = 0) -> tuple:
         c["value"] <= c["limit"] for c in checks.values()))
 
 
-def reader(metrics_dir: Path, name: str):
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name}", metrics_dir / f"{name}.py")
+def load(path: Path, module_name: str):
+    """The module in the file ``path``, loaded by its path."""
+    spec = importlib.util.spec_from_file_location(module_name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def family(name: str, root: Path = ROOT):
+    """The family module ``bench/families/<name>.py`` under ``root``."""
+    families = root / "bench" / "families"
+    path = families / f"{name}.py"
+    if not path.is_file():
+        known = sorted(p.stem for p in families.glob("*.py"))
+        raise SystemExit(f"unknown model family {name!r}; known: {known}")
+    mod = load(path, f"bench_family_{name}")
+    missing = [n for n in FAMILY_NAMES if not hasattr(mod, n)]
+    if missing:
+        raise SystemExit(f"{path} lacks {missing}")
+    return mod
+
+
+def reader(metrics_dir: Path, name: str):
+    return load(metrics_dir / f"{name}.py", f"bench_metric_{name}").read
 
 
 def use_compile_cache() -> None:
     """JAX's persistent cache at a fixed path in the checkout, unless
-    ``JAX_COMPILATION_CACHE_DIR`` names one; every program is kept."""
+    ``JAX_COMPILATION_CACHE_DIR`` names one; every program is kept.  The
+    key holds the programs' metadata, so an executable compiled before a
+    change of op names (named scopes) is not served for the new program."""
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(CACHE / "jax")
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def run(spec: dict, seed: int, seconds: float, traced: bool, devices,
         t_start: float = T_START) -> dict:
     """One run of the cell ``spec`` on ``devices``; prints and returns the
     result."""
-    cfg, mix = spec["config"], spec["mix"]
-    peaks = flops.chip_peaks(devices[0].device_kind)
+    cfg, mix, fam = spec["config"], spec["mix"], spec["family"]
+    chip = peaks.chip_peaks(devices[0].device_kind)
     parts = {"jax_init": time.time() - t_start}
-    trace_dir = CACHE / "trace" / spec["name"]
     with CompileClock() as clock:
         t = time.perf_counter()
-        cell = TrainCell(cfg, mix, devices, CACHE / "run" / spec["name"])
+        cell = TrainCell(fam, cfg, mix, devices, CACHE / "run" / spec["name"])
         parts["build"] = time.perf_counter() - t
         feed, rows, made = cell.start(seed)
         parts.update(made)
-        probe = Probe(cell.step)
+        probe = Probe(cell.step, fam.LOSSES)
         t = time.perf_counter()
         prog, batches = cell.first_steps(probe, feed)
         parts["first_steps"] = time.perf_counter() - t
@@ -123,10 +148,8 @@ def run(spec: dict, seed: int, seconds: float, traced: bool, devices,
             seconds * TIMING_STEPS / parts["timing_steps"]))
         compile_s, setup_compiles = clock.seconds, clock.compiles
         probe.reset()
-        if traced:
-            shutil.rmtree(trace_dir, ignore_errors=True)
-            jax.profiler.start_trace(str(trace_dir),
-                                     profiler_options=PROFILE_OPTIONS)
+        if traced:      # the session's trace is read in memory: no file
+            session = _profiler.ProfilerSession(PROFILE_OPTIONS)
         setup_s = time.time() - t_start
         t0 = time.perf_counter()
         with TraceAnnotation(trace.WINDOW_SPAN):
@@ -134,7 +157,9 @@ def run(spec: dict, seed: int, seconds: float, traced: bool, devices,
             jax.block_until_ready(cell.state)
         t1 = time.perf_counter()
         if traced:
-            jax.profiler.stop_trace()
+            t = time.perf_counter()
+            profile = session.stop_and_get_profile_data()
+            trace_s = {"stop": time.perf_counter() - t}
         window_compiles = clock.compiles - setup_compiles
     print("setup: " + " | ".join(f"{k} {v:.3f} s" for k, v in parts.items())
           + f" | compile_s {compile_s:.3f} | total {setup_s:.3f} s")
@@ -159,13 +184,20 @@ def run(spec: dict, seed: int, seconds: float, traced: bool, devices,
               "memory_peak_bytes": memory}
     result = {"correct": False, "attempted": n_steps, "failed": failed}
     if traced:
-        red = trace.reduce_timelines(*trace.read_profile(trace_dir),
-                                     n_steps=n_steps)
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        t = time.perf_counter()
+        timelines = trace.read_xspace(profile)
+        del profile
+        trace_s["read"] = time.perf_counter() - t
+        t = time.perf_counter()
+        red = trace.reduce_timelines(*timelines, n_steps=n_steps)
+        trace_s["reduce"] = time.perf_counter() - t
+        n_ops = sum(d["ops"].ids.size for d in timelines[0].values())
+        del timelines
+        print("trace: " + " | ".join(f"{k} {v:.3f} s" for k, v in
+                                     trace_s.items()) + f" | ops {n_ops}")
         info = {"trace": red, "tokens_per_s_per_chip": tps_chip,
-                "flops_per_token": flops.train_flops_per_token(
-                    cfg, mix["seq_len"], mix["n_predictions"]),
-                "peaks": peaks, "compile_s": compile_s}
+                "flops_per_token": fam.train_flops_per_token(cfg, mix),
+                "peaks": chip, "compile_s": compile_s}
         metrics = {}
         for m in spec["per_layer"]:
             value = reader(spec["metrics_dir"], m["name"])(info)

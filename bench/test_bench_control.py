@@ -2,9 +2,10 @@
 
 Each test drives a whole run of bench/run.py at a tiny width on the CPU,
 past its look for a chip, with one fault planted under the timed path:
-a step that hands back its state unchanged, a step that takes the mean
-over half of its batch, and, on four virtual devices, a data-parallel step
-whose gradient exchange is left out.  The control, the reference computed
+a step that hands back its state unchanged and a step that takes the mean
+over half of its batch, on one device and on the four-chip cell's four
+virtual devices, and there also a data-parallel step whose gradient
+exchange is left out.  The control, the reference computed
 in fp8 in the program's place, must stand apart from the sound program.
 """
 import json
@@ -44,7 +45,7 @@ cell_mod.make_train_step_dp = planted
 
 RUN = """
     import jax
-    spec = tiny("bert-large.p1-s128", data_parallel={dp})
+    spec = tiny({name!r})
     bench_run.run(spec, 11, 1.0, False, jax.devices()[:spec["chips"]])
 """
 
@@ -64,7 +65,14 @@ def _failed_checks(out: str) -> list:
 @pytest.mark.parametrize("fault", sorted(FAULTS))
 def test_fault_in_the_step_is_not_correct(fault):
     out = _run_in_child(_plant(FAULTS[fault]) + textwrap.dedent(
-        RUN.format(dp=None)), 1)
+        RUN.format(name="bert-large.p1-s128")), 1)
+    assert _failed_checks(out)
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_fault_in_the_four_chip_step_is_not_correct(fault):
+    out = _run_in_child(_plant(FAULTS[fault]) + textwrap.dedent(
+        RUN.format(name="bert-large.p1-s128-dp4")), 4)
     assert _failed_checks(out)
 
 
@@ -74,7 +82,7 @@ def test_left_out_exchange_is_not_correct():
         broken = step
     """
     out = _run_in_child(_plant(broken, local) + textwrap.dedent(
-        RUN.format(dp=4)), 4)
+        RUN.format(name="bert-large.p1-s128-dp4")), 4)
     assert _failed_checks(out)
 
 
@@ -95,7 +103,8 @@ def test_the_fp8_control_is_told_apart():
                               head_dim=64, d_ff=1024, vocab_size=4096,
                               max_position=128)
         spec["mix"].update(batch_per_chip=16, rows_per_chip=256)
-        cell = TrainCell(spec["config"], spec["mix"], jax.devices()[:1],
+        cell = TrainCell(spec["family"], spec["config"], spec["mix"],
+                         jax.devices()[:1],
                          bench_run.CACHE / "run" / "control-test")
         for seed in (3, 4, 5):
             print(json.dumps(control.readings(

@@ -1,4 +1,4 @@
-"""bench/flops.py and bench/peaks.json, checked on the CPU.
+"""The BERT family's model FLOPs and bench/peaks.json, checked on the CPU.
 
 The model-FLOPs function is compared with the matmuls of the program's own
 BERT loss and gradient at a reduced width: counted exactly from the traced
@@ -11,7 +11,8 @@ import math
 import jax
 import pytest
 
-from bench import flops
+from bench.peaks import chip_peaks
+from bench.run import family
 from repro.configs import get_config
 from repro.configs.base import InputShape
 from repro.core.amp import make_policy
@@ -22,6 +23,8 @@ from repro.models import api
 SMALL = dict(n_layers=1, d_model=256, n_heads=4, head_dim=64, d_ff=1024,
              vocab_size=2048)
 SEQ, PRED, BATCH = 128, 20, 8
+MIX = {"seq_len": SEQ, "n_predictions": PRED}
+BERT = family("bert")
 
 
 def _dot_flops(jaxpr) -> float:
@@ -57,7 +60,7 @@ def _grad_program(n_layers):
 
 def _model_flops(n_layers=1):
     cfg = dict(SMALL, n_layers=n_layers)
-    return flops.train_flops_per_token(cfg, SEQ, PRED) * SEQ * BATCH
+    return BERT.train_flops_per_token(cfg, MIX) * SEQ * BATCH
 
 
 def test_matches_the_programs_matmuls():
@@ -78,14 +81,14 @@ def test_is_most_of_what_xla_counts():
 def test_bert_large_phase1_per_token():
     cfg = {"n_layers": 24, "d_model": 1024, "n_heads": 16, "head_dim": 64,
            "d_ff": 4096, "vocab_size": 30522}
-    assert flops.train_flops_per_token(cfg, 128, 20) == \
+    assert BERT.train_flops_per_token(cfg, MIX) == \
         pytest.approx(1.88e9, rel=5e-3)
 
 
 def test_peaks_are_keyed_by_device_kind():
-    v5e = flops.chip_peaks("TPU v5 lite")
+    v5e = chip_peaks("TPU v5 lite")
     assert v5e["bf16_flops_per_s"] == 197e12
     assert v5e["hbm_bytes_per_s"] == 819e9
     assert "TPU v5e" in v5e["source"]
     with pytest.raises(KeyError, match="no published peaks"):
-        flops.chip_peaks("cpu")
+        chip_peaks("cpu")
